@@ -12,9 +12,9 @@ and ``model_bytes_*`` are machine-independent operation/traffic models
 (exact-gated in CI under the ``model_`` prefix — drift means the
 kernel's work model changed, not that the host got slower), and
 ``roofline_flops_frac_*`` / ``roofline_bw_frac_*`` divide the achieved
-rates by the TPU v5e chip peaks in ``repro.launch.mesh``. On the CI
-host the fractions are interpret-mode CPU numbers (tiny by
-construction); on TPU they read directly as roofline fractions.
+rates by the chip's peaks in ``repro.launch.mesh.CHIP_PEAKS``. They are
+emitted only on a device in that table; elsewhere (the CPU, where the
+kernels run in interpret mode) the notes say they were not measured.
 """
 from __future__ import annotations
 
@@ -35,7 +35,7 @@ from repro.kernels import (decode_mean_int2, decode_mean_int4,
                            quantize_pack_int8, quantize_pack_int8_ref,
                            scd_steps_kernel, scd_steps_ref, topk_select,
                            topk_select_ref)
-from repro.launch.mesh import kernel_roofline
+from repro.launch.mesh import CHIP_PEAKS, kernel_roofline
 
 # decoded-elements cost factor: unpack ops per element before the
 # scale multiply (int8 converts only; int4/int2 mask+shift+bias)
@@ -43,14 +43,16 @@ _UNPACK_OPS = {"int8": 1, "int4": 3, "int2": 3}
 
 
 def _roofline(counters: dict, cell: str, flops: int, nbytes: int,
-              t: float) -> None:
-    """Attach the exact work model and the achieved roofline fractions
-    of one Pallas cell to the counter dict."""
+              t: float, peaks) -> None:
+    """Attach the exact work model of one Pallas cell to the counter
+    dict, and its achieved roofline fractions when the device has
+    published peaks (``peaks`` is None otherwise)."""
     counters[f"model_flops_{cell}"] = int(flops)
     counters[f"model_bytes_{cell}"] = int(nbytes)
-    rl = kernel_roofline(float(flops), float(nbytes), t)
-    counters[f"roofline_flops_frac_{cell}"] = rl["flops_frac_of_peak"]
-    counters[f"roofline_bw_frac_{cell}"] = rl["bw_frac_of_hbm"]
+    if peaks is not None:
+        rl = kernel_roofline(float(flops), float(nbytes), t, peaks)
+        counters[f"roofline_flops_frac_{cell}"] = rl["flops_frac_of_peak"]
+        counters[f"roofline_bw_frac_{cell}"] = rl["bw_frac_of_hbm"]
 
 
 @benchmark("kernels", figures="§kernels",
@@ -61,6 +63,8 @@ def run(ctx: BenchContext) -> dict:
     policy = TimingPolicy(warmup=1, reps=reps)
     rng = np.random.default_rng(ctx.seed)
     rows, timings, counters = [], {}, {}
+    kind = jax.devices()[0].device_kind
+    peaks = CHIP_PEAKS.get(kind)
     # -- tiled SCD: lane-tiled Pallas kernel vs the jnp reference loop.
     # The rework streams (h_blk, S, 128) column tiles through VMEM, so
     # the kernel must hold its own against the oracle even in interpret
@@ -95,7 +99,7 @@ def run(ctx: BenchContext) -> dict:
             timings[cell] = t
             counters[f"gflops_{cell}"] = round(flops / t / 1e9, 3)
         _roofline(counters, f"scd_pallas_interp_m{m}_H{H}",
-                  flops, scd_bytes, t_ker)
+                  flops, scd_bytes, t_ker, peaks)
         ratio = t_ref / t_ker
         counters[f"scd_ratio_vs_ref_m{m}_H{H}"] = round(ratio, 3)
         if ctx.tier == "smoke" and (m, n, H) == big_m:
@@ -137,7 +141,7 @@ def run(ctx: BenchContext) -> dict:
             spe = 8 // bits
             q_flops = 6 * L + (spe - 1) * 2 * math.ceil(L / spe)
             _roofline(counters, f"{name}_pallas_interp_L{L}",
-                      q_flops, 4 * L + wire, t_ker)
+                      q_flops, 4 * L + wire, t_ker, peaks)
     # -- fused decode+mean: the gather-side kernels behind
     # decode_stacked_mean, against the sequential jnp oracle in
     # repro.kernels.ref — the contract that closed the f32-intermediate
@@ -180,7 +184,7 @@ def run(ctx: BenchContext) -> dict:
             d_flops = (K * L * _UNPACK_OPS[codec_name] + K * L
                        + (K - 1) * L + L)
             _roofline(counters, f"{name}_pallas_interp_K{K}_L{L}",
-                      d_flops, K * wire + 4 * L, t_ker)
+                      d_flops, K * wire + 4 * L, t_ker, peaks)
     # -- fused top-k select: k argmax+mask sweeps in VMEM vs the
     # lax.top_k oracle; values, indices and threshold all bit-identical
     topk_ref_fn = jax.jit(topk_select_ref)
@@ -206,14 +210,18 @@ def run(ctx: BenchContext) -> dict:
         # |x| pass + k sweeps of (max, select, mask); ships 2 words
         # per kept entry + the threshold
         _roofline(counters, f"topk_pallas_interp_L{L}",
-                  L + 3 * k * L, 4 * L + 8 * k + 4, t_ker)
+                  L + 3 * k * L, 4 * L + 8 * k + 4, t_ker, peaks)
     notes = ["pallas numbers are interpret-mode (CPU emulation) — "
              "correctness benchmark, not TPU speed",
              "quantize+pack, decode+mean and top-k interpret outputs "
              "asserted bit-identical to the codec oracles at every cell",
-             "roofline_*_frac counters divide achieved rates by the TPU "
-             "v5e peaks (repro.launch.mesh); model_* counters are the "
-             "machine-independent work models, exact-gated in CI"]
+             "model_* counters are the machine-independent work models, "
+             "exact-gated in CI",
+             (f"roofline_*_frac counters divide achieved rates by the "
+              f"{kind} peaks (repro.launch.mesh.CHIP_PEAKS)"
+              if peaks is not None else
+              f"roofline shares not measured: device kind {kind!r} has "
+              f"no published peaks")]
     return {"params": {"shapes": [list(s) for s in wl.kernel_shapes],
                        "quant_lengths": list(wl.quant_lengths),
                        "K": K, "reps": reps},

@@ -201,9 +201,8 @@ def lower_round_hlo(trainer, round_fn) -> str:
     the jit call cache, so single-compile still sees a cold function)."""
     import jax
     local, shared = trainer.init_state()
-    return round_fn.jitted.lower(
-        round_fn.split_keys(jax.random.key(0)), local, shared,
-        1).compile().as_text()
+    return round_fn.lower(local, shared, jax.random.key(0),
+                          1).compile().as_text()
 
 
 def compile_cell(cell: Cell, mesh=None, K: int | None = None

@@ -248,6 +248,19 @@ def _async_result(operand_shapes: tuple[Shape, ...],
     return result_shapes
 
 
+_PALLAS_RE = re.compile(
+    r"%(?P<name>[A-Za-z_]\w*?)(?:\.\d+)? = .*"
+    r"custom_call_target=\"tpu_custom_call\"")
+
+
+def pallas_kernels(hlo_text: str) -> set[str]:
+    """Names of the Pallas TPU kernels (``tpu_custom_call``s) compiled
+    into an HLO module — the ``name=`` each ``pallas_call`` was given.
+    Empty for a program compiled for the CPU, where the kernels run in
+    interpret mode."""
+    return {m.group("name") for m in _PALLAS_RE.finditer(hlo_text)}
+
+
 def lift_hlo(hlo_text: str) -> "CollectiveGraph":
     """Parse optimized HLO text into a :class:`CollectiveGraph`."""
     instructions: dict[str, Instruction] = {}

@@ -20,6 +20,7 @@ import time
 from contextlib import contextmanager
 
 from repro.bench import registry, schema
+from repro.utils.cache import enable_compilation_cache
 
 # Per-benchmark wall-clock budget (seconds) by tier; --timeout overrides.
 # The smoke budget is sized to the drivers benchmark's 24-cell
@@ -145,6 +146,8 @@ def main(argv: list[str] | None = None) -> int:
             os.environ["XLA_FLAGS"] = (
                 os.environ.get("XLA_FLAGS", "") +
                 f" --xla_force_host_platform_device_count={devices}").strip()
+
+    enable_compilation_cache()
 
     if args.list:
         registry.load_default_benchmarks()

@@ -306,9 +306,13 @@ class Int8Codec(_QuantFusedReduce, StatelessCodec):
             return quantize_pack_int8(dv)
         return self.encode_ref(dv)
 
+    def scale(self, dv: jax.Array) -> jax.Array:
+        """The per-worker absmax scale (shared with the Pallas kernel)."""
+        return _absmax_scale(dv, INT8_QMAX, 1e-30)
+
     def encode_ref(self, dv: jax.Array) -> tuple[jax.Array, jax.Array]:
         """The jnp path (and the Pallas kernel's bit-exact oracle)."""
-        scale = _absmax_scale(dv, INT8_QMAX, 1e-30)
+        scale = self.scale(dv)
         q = jnp.clip(jnp.round(dv / scale), -INT8_QMAX,
                      INT8_QMAX).astype(jnp.int8)
         return q, scale
@@ -342,9 +346,13 @@ class Int4Codec(_QuantFusedReduce, StatelessCodec):
             return quantize_pack_int4(dv)
         return self.encode_ref(dv)
 
+    def scale(self, dv: jax.Array) -> jax.Array:
+        """The per-worker absmax scale (shared with the Pallas kernel)."""
+        return _absmax_scale(dv, INT4_SCALE_DIV, 0.0)
+
     def encode_ref(self, dv: jax.Array) -> tuple[jax.Array, jax.Array]:
         """The jnp path (and the Pallas kernel's bit-exact oracle)."""
-        scale = _absmax_scale(dv, INT4_SCALE_DIV, 0.0)
+        scale = self.scale(dv)
         lo, hi = _split_halves(dv)
         qlo = jnp.clip(jnp.round(lo / scale), -INT4_QMAX,
                        INT4_QMAX).astype(jnp.int32) + 8
@@ -391,10 +399,14 @@ class Int2Codec(_QuantFusedReduce, StatelessCodec):
             return quantize_pack_int2(dv)
         return self.encode_ref(dv)
 
+    def scale(self, dv: jax.Array) -> jax.Array:
+        """The per-worker absmax scale (shared with the Pallas kernel)."""
+        absmax = jnp.max(jnp.abs(dv))
+        return jnp.where(absmax > 0, absmax * INT2_SCALE_MUL, 1.0)
+
     def encode_ref(self, dv: jax.Array) -> tuple[jax.Array, jax.Array]:
         """The jnp path (and the Pallas kernel's bit-exact oracle)."""
-        absmax = jnp.max(jnp.abs(dv))
-        scale = jnp.where(absmax > 0, absmax * INT2_SCALE_MUL, 1.0)
+        scale = self.scale(dv)
         rows = _split_quarters(dv)
         q = jnp.clip(jnp.round(rows / scale), -INT2_QMAX,
                      INT2_QMAX).astype(jnp.int32) + 2

@@ -257,10 +257,10 @@ class MinibatchSGD:
     # legacy single-device loop (global row sampling)
     # ------------------------------------------------------------------
     def _build_step(self):
-        cfg, A, b, batch = self.cfg, self.A, self.b, self.batch
+        cfg, batch = self.cfg, self.batch
 
         @jax.jit
-        def step(alpha, t, key):
+        def step(A, b, alpha, t, key):
             rows = jax.random.choice(key, A.shape[0], shape=(batch,),
                                      replace=False)
             A_s, b_s = A[rows], b[rows]
@@ -294,7 +294,7 @@ class MinibatchSGD:
         hist = History(p_star=p_star, p_zero=p_zero)
         for t in range(1, rounds + 1):
             key, sub = jax.random.split(key)
-            alpha = self._step(alpha, jnp.asarray(t), sub)
+            alpha = self._step(self.A, self.b, alpha, jnp.asarray(t), sub)
             if t % record_every == 0 or t == rounds:
                 p = float(primal_objective(self.problem, self.A, self.b, alpha))
                 hist.rounds.append(t)
@@ -349,7 +349,8 @@ class MinibatchSGD:
         ``round_fn(local, alpha, key, t)``."""
         assert mesh.devices.size == self.cfg.K, (mesh.devices.size, self.cfg.K)
         return dist.build_sharded_round(self._algo, self.exchange,
-                                        self._data, mesh)
+                                        dist.place_data(mesh, self._data),
+                                        mesh)
 
     def run_sharded(self, rounds: int, mesh: Mesh | None = None,
                     record_every: int = 10,

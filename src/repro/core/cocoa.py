@@ -245,10 +245,11 @@ class CoCoATrainer:
     # the one record loop both drivers share
     # ------------------------------------------------------------------
     def _record_loop(self, round_fn, alpha, w, rounds: int,
-                     record_every: int,
-                     target_eps: float | None) -> History:
+                     record_every: int, target_eps: float | None,
+                     p_star: float | None) -> History:
         key = jax.random.key(self.cfg.seed)
-        hist = History(p_star=self.p_star, p_zero=self.p_zero)
+        hist = History(p_star=self.p_star if p_star is None else p_star,
+                       p_zero=self.p_zero)
         last_t = 0
         for t in range(rounds):
             last_t = t + 1
@@ -276,10 +277,13 @@ class CoCoATrainer:
     # virtual-worker (vmap) driver
     # ------------------------------------------------------------------
     def run(self, rounds: int, record_every: int = 1,
-            target_eps: float | None = None) -> History:
+            target_eps: float | None = None,
+            p_star: float | None = None) -> History:
+        """``p_star``, when the caller already knows the optimum, skips
+        the host solve behind :attr:`p_star`."""
         alpha, w = self.init_state()
         return self._record_loop(self._round_fn, alpha, w, rounds,
-                                 record_every, target_eps)
+                                 record_every, target_eps, p_star)
 
     # ------------------------------------------------------------------
     # shard_map driver (real distribution over devices)
@@ -290,17 +294,19 @@ class CoCoATrainer:
         ``round_fn(alpha_st, w, key, t)``."""
         assert mesh.devices.size == self.cfg.K, (mesh.devices.size, self.cfg.K)
         return dist.build_sharded_round(self._algo, self.exchange,
-                                        self._data, mesh)
+                                        dist.place_data(mesh, self._data),
+                                        mesh)
 
     def run_sharded(self, rounds: int, mesh: Mesh | None = None,
                     record_every: int = 1,
-                    target_eps: float | None = None) -> History:
+                    target_eps: float | None = None,
+                    p_star: float | None = None) -> History:
         if mesh is None:
             mesh = compat.make_mesh((self.cfg.K,), ("workers",))
         round_fn = self.build_sharded_round(mesh)
         alpha, w = dist.place_state(mesh, *self.init_state())
         return self._record_loop(round_fn, alpha, w, rounds, record_every,
-                                 target_eps)
+                                 target_eps, p_star)
 
     # ------------------------------------------------------------------
     def objective_of(self, alpha_global: np.ndarray) -> float:

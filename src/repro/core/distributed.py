@@ -982,8 +982,12 @@ def build_virtual_round(algo: RoundAlgorithm, exchange=None, data=None,
     or a spec string (``"compressed:int4/stale:k=2/drop:1@5"``); the
     keyword ``scheme=`` / ``mode=`` spellings are deprecated aliases.
 
-    Returns jitted ``round_fn(local, shared, key, t) -> (local_new,
-    shared_new, metric)``. ``use_map`` runs workers with ``lax.map``
+    Returns ``round_fn(local, shared, key, t) -> (local_new,
+    shared_new, metric)``. The data reaches the jitted round
+    (``round_fn.jitted``) as its first argument, never as a closure
+    constant, so the program text does not grow with the data;
+    ``round_fn.lower(local, shared, key, t)`` lowers it ahead of time.
+    ``use_map`` runs workers with ``lax.map``
     instead of ``vmap`` (needed for interpret-mode Pallas solvers).
     Under a stale mode the ``shared`` slot is the ``(shared, queue)``
     pair from :func:`init_exchange_state`: workers compute against
@@ -1011,7 +1015,7 @@ def build_virtual_round(algo: RoundAlgorithm, exchange=None, data=None,
                 and getattr(algo, "live_reweight", False))
 
     @jax.jit
-    def round_fn(local, shared, key, t=1):
+    def jitted(data, local, shared, key, t):
         if stateful:
             local, cstate = local
         if xmode.stale:
@@ -1070,6 +1074,12 @@ def build_virtual_round(algo: RoundAlgorithm, exchange=None, data=None,
         return local_out, shared_out, algo.finalize_metric(metric_shared,
                                                            metric_sum)
 
+    def round_fn(local, shared, key, t=1):
+        return jitted(data, local, shared, key, t)
+
+    round_fn.jitted = jitted
+    round_fn.lower = lambda local, shared, key, t=1: jitted.lower(
+        data, local, shared, key, t)
     round_fn.exchange = ex
     round_fn.mode = xmode
     round_fn.stateful_codec = stateful
@@ -1084,10 +1094,13 @@ def build_sharded_round(algo: RoundAlgorithm, exchange=None, data=None,
 
     ``exchange`` is an :class:`ExchangeConfig`, a :class:`CommScheme`,
     or a spec string; the keyword ``scheme=`` / ``mode=`` spellings are
-    deprecated aliases. Returns jitted ``round_fn(local, shared, key,
-    t) -> (local_new, shared_new, metric)`` with ``local``/``shared``
-    donated. The mesh axis size must equal the worker count K (the
-    leading dim of every ``data`` leaf and of ``local``). Under a stale
+    deprecated aliases. Returns ``round_fn(local, shared, key, t) ->
+    (local_new, shared_new, metric)`` with ``local``/``shared`` donated;
+    the jitted inner ``round_fn.jitted(data, keys, local, shared, t)``
+    takes the data as an argument, like the virtual driver's, and
+    ``round_fn.lower(local, shared, key, t)`` lowers it ahead of time.
+    The mesh axis size must equal the worker count K (the leading dim
+    of every ``data`` leaf and of ``local``). Under a stale
     mode the ``shared`` slot is the ``(shared, queue)`` pair — same
     delayed apply, same collectives (the wire traffic is
     mode-independent, which the drivers benchmark asserts against the
@@ -1166,16 +1179,11 @@ def build_sharded_round(algo: RoundAlgorithm, exchange=None, data=None,
         in_specs=(data_specs, P(axis), P(axis), P(None), P()),
         out_specs=(P(axis), P(None), P()))
 
-    @functools.partial(jax.jit, donate_argnums=(1, 2) if donate else ())
-    def jitted(keys, local, shared, t):
-        return sharded(data, local, keys, shared, t)
-
+    # data is an argument, never a closure constant: a captured array
+    # is embedded in the program text (which then grows with the data),
+    # and jit forbids capturing the global arrays of a multi-process run
     @functools.partial(jax.jit, donate_argnums=(2, 3) if donate else ())
-    def jitted_data(data_arg, keys, local, shared, t):
-        # data as an explicit argument instead of a closure constant:
-        # multi-process runs (launch.dist) place the data as GLOBAL
-        # arrays, and jit forbids closing over arrays that span
-        # non-addressable devices — traced only if actually used
+    def jitted(data_arg, keys, local, shared, t):
         return sharded(data_arg, local, keys, shared, t)
 
     def split_keys(key):
@@ -1187,12 +1195,14 @@ def build_sharded_round(algo: RoundAlgorithm, exchange=None, data=None,
         return jax.random.key_data(jax.random.split(key, K))
 
     def round_fn(local, shared, key, t=1):
-        return jitted(split_keys(key), local, shared, t)
+        return jitted(data, split_keys(key), local, shared, t)
 
     # the jitted inner + key derivation, exposed for AOT lowering (HLO
-    # collective-traffic inspection in benches/tests) and state placement
+    # collective-traffic inspection in benches/tests) and for callers
+    # that place the data and keys themselves (launch.dist)
     round_fn.jitted = jitted
-    round_fn.jitted_data = jitted_data
+    round_fn.lower = lambda local, shared, key, t=1: jitted.lower(
+        data, split_keys(key), local, shared, t)
     round_fn.split_keys = split_keys
     round_fn.mesh = mesh
     round_fn.exchange = ex
@@ -1200,6 +1210,15 @@ def build_sharded_round(algo: RoundAlgorithm, exchange=None, data=None,
     round_fn.stateful_codec = stateful
     round_fn.flush = _make_flush(algo, xmode)
     return round_fn
+
+
+def place_data(mesh: Mesh, data, axis: str | None = None):
+    """Device-put the ``(K, ...)`` data leaves partitioned over the
+    worker axis, once, before the sharded round is built: an array left
+    on one device would be moved to the mesh on every round."""
+    sharding = NamedSharding(mesh, P(axis or mesh.axis_names[0]))
+    return jax.tree_util.tree_map(lambda x: jax.device_put(x, sharding),
+                                  data)
 
 
 def place_state(mesh: Mesh, local, shared, axis: str | None = None):

@@ -10,13 +10,17 @@ scale by the per-worker f32 scale and accumulate the f32 sum (or mean)
 worker by worker, so the only f32 tensor that ever exists is the
 ``(L,)``-sized accumulator — no K x L f32 HBM round-trip.
 
-Layouts mirror the encode kernels in ``repro.kernels.quant``:
+Layouts mirror the encode kernels in ``repro.kernels.quant``: the
+``(K, wire)`` payload is laid out lane-dense as ``(K, R, 128)``
+(``repro.kernels.tiling``) and the grid streams blocks of rows, each
+independent of the others, with the K per-worker scales in SMEM:
 
-  * int8: (K, L) int8 payload + (K, 1) f32 scales -> (1, L) f32.
-  * int4: (K, L/2) packed bytes -> (2, L/2) f32 split-half rows
-    (element ``i`` pairs with ``i + ceil(L/2)``), reshaped/sliced back
-    to (L,) by the wrapper.
-  * int2: (K, L/4) packed bytes -> (4, L/4) f32 split-quarter rows.
+  * int8: (K, R, 128) int8 payload -> (1, R, 128) f32.
+  * int4: (K, R, 128) packed bytes -> (2, R, 128) f32 split-half
+    planes (element ``i`` pairs with ``i + ceil(L/2)``), reshaped and
+    sliced back to (L,) by the wrapper.
+  * int2: (K, R, 128) packed bytes -> (4, R, 128) f32 split-quarter
+    planes.
 
 Reduction-order contract: the per-worker rows are accumulated
 SEQUENTIALLY in canonical worker order (k = 0..K-1) and the mean is the
@@ -31,8 +35,8 @@ other — observed on CPU, where the contracted chain is 1 ulp off the
 strict one and ``lax.optimization_barrier`` does NOT stop it (the
 contraction happens inside one fused loop at codegen, below HLO). The
 select is semantically free: quantized products are finite by
-construction. The wrappers pad the lane dimension to 128
-with zero bytes (padded codes decode to exact zeros under every
+construction. The wrappers pad the wire dimension to whole
+row blocks with zero bytes (padded codes decode to exact zeros under every
 codec's biased grid... int8's zero byte IS code 0; int4/int2 padded
 bytes decode to the biased code -8/-2 times the scale but are sliced
 off before they can be observed), run compiled on TPU and in interpret
@@ -45,16 +49,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-_LANE = 128  # TPU lane width: pad the payload's wire dimension
-
-
-def _pad_lanes(x: jax.Array) -> jax.Array:
-    pad = -x.shape[-1] % _LANE
-    if pad:
-        x = jnp.concatenate(
-            [x, jnp.zeros(x.shape[:-1] + (pad,), x.dtype)], axis=-1)
-    return x
+from repro.kernels.tiling import LANE, row_tiles, to_rows
+from repro.utils import compat
 
 
 def _no_fma(row: jax.Array) -> jax.Array:
@@ -68,106 +66,87 @@ def _no_fma(row: jax.Array) -> jax.Array:
     return jnp.where(jnp.isfinite(row), row, jnp.float32(0.0))
 
 
-def _accumulate(rows, mult: float | None):
-    """Sequential f32 accumulation over the K decoded rows — the ONE
-    reduction-order contract shared with the jnp oracle."""
-    acc = _no_fma(rows[0])
-    for r in rows[1:]:
-        acc = acc + _no_fma(r)
-    return acc if mult is None else acc * mult
+def _unpack_int8(q):
+    return (q,)
 
 
-def _dec8_kernel(K: int, mult: float | None, q_ref, s_ref, out_ref):
-    rows = [q_ref[k:k + 1, :].astype(jnp.float32) * s_ref[k, 0]
-            for k in range(K)]
-    out_ref[...] = _accumulate(rows, mult)
+def _unpack_int4(p):
+    p = p.astype(jnp.int32)
+    return (p & 0xF) - 8, (p >> 4) - 8
 
 
-def _dec4_kernel(K: int, mult: float | None, p_ref, s_ref, out_ref):
-    rows = []
+def _unpack_int2(p):
+    p = p.astype(jnp.int32)
+    return tuple(((p >> (2 * f)) & 0x3) - 2 for f in range(4))
+
+
+def _decode_kernel(K: int, mult: float | None, unpack, s_ref, p_ref,
+                   out_ref):
+    """Sequential f32 accumulation over the K decoded worker rows, one
+    accumulator per packed plane — the ONE reduction-order contract
+    shared with the jnp oracle."""
+    acc = None
     for k in range(K):
-        p = p_ref[k:k + 1, :].astype(jnp.int32)
-        q = jnp.concatenate([p & 0xF, p >> 4], axis=0) - 8   # (2, W)
-        rows.append(q.astype(jnp.float32) * s_ref[k, 0])
-    out_ref[...] = _accumulate(rows, mult)
+        rows = [_no_fma(q.astype(jnp.float32) * s_ref[k])
+                for q in unpack(p_ref[k])]
+        acc = rows if acc is None else [a + r for a, r in zip(acc, rows)]
+    for f, a in enumerate(acc):
+        out_ref[f] = a if mult is None else a * mult
 
 
-def _dec2_kernel(K: int, mult: float | None, p_ref, s_ref, out_ref):
-    rows = []
-    for k in range(K):
-        p = p_ref[k:k + 1, :].astype(jnp.int32)
-        q = jnp.concatenate([p & 0x3, (p >> 2) & 0x3, (p >> 4) & 0x3,
-                             (p >> 6) & 0x3], axis=0) - 2    # (4, W)
-        rows.append(q.astype(jnp.float32) * s_ref[k, 0])
-    out_ref[...] = _accumulate(rows, mult)
-
-
-def _reduce_mult(K: int, mean: bool) -> float | None:
-    """``None`` = plain sum (no trailing multiply); the mean is the sum
-    times the f32-rounded 1/K, same constant the oracle uses."""
-    return (1.0 / K) if mean else None
+_UNPACK = {"int8": (_unpack_int8, 1), "int4": (_unpack_int4, 2),
+           "int2": (_unpack_int2, 4)}
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("length", "mean", "interpret"))
+                   static_argnames=("codec", "length", "mean", "interpret"))
+def _decode_reduce(codec: str, payload: jax.Array, scales: jax.Array,
+                   length: int, mean: bool, interpret: bool | None
+                   ) -> jax.Array:
+    unpack, planes = _UNPACK[codec]
+    K, W = payload.shape
+    block, rows = row_tiles(W)
+    # None = plain sum (no trailing multiply); the mean is the sum
+    # times the f32-rounded 1/K, same constant the oracle uses
+    mult = (1.0 / K) if mean else None
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, K, mult, unpack),
+        grid=(rows // block,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),        # scales
+                  pl.BlockSpec((K, block, LANE), lambda i: (0, i, 0))],
+        out_specs=pl.BlockSpec((planes, block, LANE), lambda i: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((planes, rows, LANE), jnp.float32),
+        interpret=compat.default_interpret(interpret),
+        name=f"decode_reduce_{codec}",
+    )(scales.reshape(K).astype(jnp.float32), to_rows(payload, rows))
+    return out.reshape(planes, rows * LANE)[:, :W].reshape(-1)[:length]
+
+
 def decode_reduce_int8(q: jax.Array, scales: jax.Array, length: int, *,
                        mean: bool = True, interpret: bool | None = None
                        ) -> jax.Array:
     """Fused decode+reduce of an all-gathered int8 payload: ``(K, L)``
     int8 + ``(K,)`` scales -> the ``(L,)`` f32 sum (or mean) — bit-
     identical to ``decode_stacked_ref('int8', ...)``."""
-    from repro.utils import compat
-    interpret = compat.default_interpret(interpret)
-    K = q.shape[0]
-    x = _pad_lanes(q)
-    out = pl.pallas_call(
-        functools.partial(_dec8_kernel, K, _reduce_mult(K, mean)),
-        out_shape=jax.ShapeDtypeStruct((1, x.shape[1]), jnp.float32),
-        interpret=interpret,
-    )(x, scales.reshape(K, 1).astype(jnp.float32))
-    return out[0, :length]
+    return _decode_reduce("int8", q, scales, length, mean, interpret)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("length", "mean", "interpret"))
 def decode_reduce_int4(packed: jax.Array, scales: jax.Array, length: int,
                        *, mean: bool = True,
                        interpret: bool | None = None) -> jax.Array:
     """Fused decode+reduce of an all-gathered packed-int4 payload:
     ``(K, ceil(L/2))`` uint8 + ``(K,)`` scales -> the ``(L,)`` f32 sum
     (or mean) — bit-identical to ``decode_stacked_ref('int4', ...)``."""
-    from repro.utils import compat
-    interpret = compat.default_interpret(interpret)
-    K = packed.shape[0]
-    half = packed.shape[1]
-    x = _pad_lanes(packed)
-    out = pl.pallas_call(
-        functools.partial(_dec4_kernel, K, _reduce_mult(K, mean)),
-        out_shape=jax.ShapeDtypeStruct((2, x.shape[1]), jnp.float32),
-        interpret=interpret,
-    )(x, scales.reshape(K, 1).astype(jnp.float32))
-    return out[:, :half].reshape(2 * half)[:length]
+    return _decode_reduce("int4", packed, scales, length, mean, interpret)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("length", "mean", "interpret"))
 def decode_reduce_int2(packed: jax.Array, scales: jax.Array, length: int,
                        *, mean: bool = True,
                        interpret: bool | None = None) -> jax.Array:
     """Fused decode+reduce of an all-gathered packed-int2 payload:
     ``(K, ceil(L/4))`` uint8 + ``(K,)`` scales -> the ``(L,)`` f32 sum
     (or mean) — bit-identical to ``decode_stacked_ref('int2', ...)``."""
-    from repro.utils import compat
-    interpret = compat.default_interpret(interpret)
-    K = packed.shape[0]
-    quarter = packed.shape[1]
-    x = _pad_lanes(packed)
-    out = pl.pallas_call(
-        functools.partial(_dec2_kernel, K, _reduce_mult(K, mean)),
-        out_shape=jax.ShapeDtypeStruct((4, x.shape[1]), jnp.float32),
-        interpret=interpret,
-    )(x, scales.reshape(K, 1).astype(jnp.float32))
-    return out[:, :quarter].reshape(4 * quarter)[:length]
+    return _decode_reduce("int2", packed, scales, length, mean, interpret)
 
 
 # codec-name dispatch used by repro.comm.codec's on-TPU path

@@ -20,10 +20,14 @@ TPU adaptation (vs the CPU/C++ original):
     update run at full width. rho is the kernel's resident VMEM f32
     accumulator (constant index_map), exactly the paper's "persistent
     local memory" idea pushed down the memory hierarchy.
-  * The per-step scalars — sigma*||c_j||^2, 1/denom and the soft-
-    threshold level lam_l1/denom — are precomputed VECTORIZED outside
-    the kernel and streamed as (h_blk, 1) columns, so the serial
-    H-step loop carries no divides, only mul/add and the reduction.
+  * The per-step scalars — the coordinate index, sigma*||c_j||^2,
+    1/denom and the soft-threshold level lam_l1/denom — are precomputed
+    VECTORIZED outside the kernel and prefetched whole into SMEM, so
+    the serial H-step loop carries no divides, only mul/add and the
+    reduction. The alpha block lives in SMEM too: the dynamically
+    indexed ``alpha[j]`` read and store are scalar-unit work, and the
+    TPU cannot store a scalar to VMEM. SMEM therefore holds
+    4 * H + 2 * n_local words.
   * ``h_blk`` is picked from a VMEM budget (``_auto_h_blk``) when not
     given: the double-buffered column stream is the dominant tenant, so
     h_blk ~ budget / (2 * S * 128 * 4), clamped to [8, 512].
@@ -32,10 +36,10 @@ TPU adaptation (vs the CPU/C++ original):
     the zero column leaves rho untouched), replacing the former hard
     ``H % h_blk == 0`` requirement.
 
-The grid is sequential on TPU, which the carried-in-VMEM state relies
-on. Runs compiled on TPU and in interpret mode everywhere else (same
-``compat.default_interpret`` convention as the quantize/decode
-kernels).
+The grid is sequential on TPU, which the state carried in VMEM and
+SMEM relies on. Runs compiled on TPU and in interpret mode everywhere
+else (same ``compat.default_interpret`` convention as the
+quantize/decode kernels).
 """
 from __future__ import annotations
 
@@ -45,10 +49,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tiling import LANE as _LANE
 from repro.utils import compat
 
-_LANE = 128   # TPU lane width: m is tiled to (S, _LANE)
 _VMEM_BUDGET = 4 * 1024 * 1024  # bytes allotted to the column stream
 
 
@@ -60,28 +65,36 @@ def _auto_h_blk(S: int) -> int:
     return max(8, min(512, (h // 8) * 8))
 
 
-def _scd_kernel(sigma: float, h_blk: int, cols_ref, scsq_ref, dinv_ref,
-                thr_ref, idx_ref, alpha_in_ref, w_ref,
-                alpha_ref, rho_ref):
-    """One grid step: h_blk sequential SCD updates on the VMEM state."""
+def _scd_kernel(sigma: float, h_blk: int, idx_ref, scsq_ref, dinv_ref,
+                thr_ref, cols_ref, alpha_in_ref, w_ref, alpha_ref, rho_ref):
+    """One grid step: h_blk sequential SCD updates on the kernel state.
+
+    The per-step scalars and the alpha coordinates live in SMEM (scalar
+    reads and the dynamically indexed ``alpha[j]`` store run on the
+    scalar unit); the column stream and the rho accumulator are
+    (S, 128) VMEM tiles."""
     i = pl.program_id(0)
+    base = i * h_blk
 
     @pl.when(i == 0)
     def _init():
-        alpha_ref[...] = alpha_in_ref[...]
+        def copy(j, _):
+            alpha_ref[j] = alpha_in_ref[j]
+            return 0
+        lax.fori_loop(0, alpha_ref.shape[0], copy, 0)
         rho_ref[...] = w_ref[...].astype(jnp.float32)
 
     def body(s, _):
-        j = idx_ref[s, 0]
-        c = cols_ref[s, :, :].astype(jnp.float32)       # (S, m_blk)
-        scsq = scsq_ref[s, 0]                           # sigma*||c_j||^2
-        a = alpha_ref[j, 0]
+        j = idx_ref[base + s]
+        c = cols_ref[s].astype(jnp.float32)             # (S, m_blk)
+        scsq = scsq_ref[base + s]                       # sigma*||c_j||^2
+        a = alpha_ref[j]
         rho = rho_ref[...]                              # (S, m_blk)
-        z_tilde = (scsq * a - jnp.sum(rho * c)) * dinv_ref[s, 0]
+        z_tilde = (scsq * a - jnp.sum(rho * c)) * dinv_ref[base + s]
         z = jnp.sign(z_tilde) * jnp.maximum(
-            jnp.abs(z_tilde) - thr_ref[s, 0], 0.0)
+            jnp.abs(z_tilde) - thr_ref[base + s], 0.0)
         z = jnp.where(scsq > 0, z, a)                   # padded/zero col
-        alpha_ref[j, 0] = z
+        alpha_ref[j] = z
         rho_ref[...] = rho + (sigma * (z - a)) * c
         return 0
 
@@ -122,7 +135,7 @@ def scd_pallas(cols: jax.Array, csq: jax.Array, idx: jax.Array,
 
     cols_p = jnp.pad(cols, ((0, Hp - H), (0, mp - m)))
     cols3 = cols_p.reshape(Hp, S, _LANE)
-    idx_p = jnp.pad(idx, (0, Hp - H))[:, None]
+    idx_p = jnp.pad(idx.astype(jnp.int32), (0, Hp - H))
     csq_p = jnp.pad(csq.astype(jnp.float32), (0, Hp - H))
     # per-step scalars, vectorized out of the serial loop: the kernel
     # body carries no divides (padded steps hit denom = lam_eta, which
@@ -134,27 +147,28 @@ def scd_pallas(cols: jax.Array, csq: jax.Array, idx: jax.Array,
     w3 = jnp.pad(w.astype(jnp.float32), (0, mp - m)).reshape(S, _LANE)
 
     kernel = functools.partial(_scd_kernel, float(sigma), h_blk)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     alpha_out, rho = pl.pallas_call(
         kernel,
-        grid=(Hp // h_blk,),
-        in_specs=[
-            pl.BlockSpec((h_blk, S, _LANE), lambda i: (i, 0, 0)),  # cols
-            pl.BlockSpec((h_blk, 1), lambda i: (i, 0)),   # sigma*csq
-            pl.BlockSpec((h_blk, 1), lambda i: (i, 0)),   # 1/denom
-            pl.BlockSpec((h_blk, 1), lambda i: (i, 0)),   # threshold
-            pl.BlockSpec((h_blk, 1), lambda i: (i, 0)),   # idx stream
-            pl.BlockSpec((n_local, 1), lambda i: (0, 0)),  # alpha in
-            pl.BlockSpec((S, _LANE), lambda i: (0, 0)),   # w (resident)
-        ],
-        out_specs=[
-            pl.BlockSpec((n_local, 1), lambda i: (0, 0)),  # alpha out
-            pl.BlockSpec((S, _LANE), lambda i: (0, 0)),   # rho accum
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            # idx, sigma*csq, 1/denom, threshold: whole (Hp,) in SMEM
+            num_scalar_prefetch=4,
+            grid=(Hp // h_blk,),
+            in_specs=[
+                pl.BlockSpec((h_blk, S, _LANE),
+                             lambda i, *_: (i, 0, 0)),    # cols stream
+                smem,                                     # alpha in
+                pl.BlockSpec((S, _LANE), lambda i, *_: (0, 0)),  # w
+            ],
+            out_specs=[
+                smem,                                     # alpha out
+                pl.BlockSpec((S, _LANE), lambda i, *_: (0, 0)),  # rho
+            ]),
         out_shape=[
-            jax.ShapeDtypeStruct((n_local, 1), jnp.float32),
+            jax.ShapeDtypeStruct((n_local,), jnp.float32),
             jax.ShapeDtypeStruct((S, _LANE), jnp.float32),
         ],
         interpret=interpret,
-    )(cols3, scsq[:, None], dinv[:, None], thr[:, None], idx_p,
-      alpha.astype(jnp.float32)[:, None], w3)
-    return alpha_out[:, 0], rho.reshape(mp)[:m]
+        name="scd",
+    )(idx_p, scsq, dinv, thr, cols3, alpha.astype(jnp.float32), w3)
+    return alpha_out, rho.reshape(mp)[:m]

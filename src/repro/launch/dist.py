@@ -129,11 +129,9 @@ def run(args) -> dict:
         last_t = t + 1
         key, sub = jax.random.split(key)
         keys = _global_put(round_fn.split_keys(sub), mesh, P("workers"))
-        # drive the data-as-argument jitted inner: the host-side wrapper
-        # closes over the data, and jit forbids closing over arrays
-        # spanning non-addressable devices
-        local, shared, primal = round_fn.jitted_data(data, keys, local,
-                                                     shared, t + 1)
+        # drive the jitted inner with the keys placed as global arrays
+        local, shared, primal = round_fn.jitted(data, keys, local, shared,
+                                                t + 1)
         primals.append(float(primal))   # replicated -> readable anywhere
     shared = dist.finish_run(round_fn, shared, last_t)
     local = dist.unwrap_local_state(tr.exchange, local)
@@ -187,6 +185,10 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
 
     import jax
+
+    from repro.utils.cache import enable_compilation_cache
+
+    enable_compilation_cache()
 
     if args.num_processes > 1:
         # the gloo CPU collectives client must be selected before

@@ -36,9 +36,7 @@ def run_pair(arch: str, shape: str, *, multi_pod: bool, verbose: bool = True,
     compiled = built.lowered.compile()
     t_compile = time.time() - t0
     mem = compiled.memory_analysis()
-    cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # jax < 0.5 returns a one-element list
-        cost = cost[0] if cost else {}
+    cost = compiled.cost_analysis() or {}
     coll = lift_hlo(compiled.as_text())
     n_dev = mesh.devices.size
     rec = {

@@ -14,10 +14,10 @@ which is exact for layer-homogeneous stacks (per-cycle cost is constant;
 embed/unembed/loss live in the L1 base term). Collective operand bytes
 are parsed from the compiled HLO and extrapolated the same way.
 
-Terms (TPU v5e constants in launch/mesh.py):
+Terms (TPU v5e peaks, ``chip_peaks`` in launch/mesh.py):
     compute    = FLOPs_per_device / 197e12
     memory     = bytes_per_device / 819e9
-    collective = collective_bytes_per_device / 50e9
+    collective = collective_bytes_per_device / 50e9  (one ICI link)
 MODEL_FLOPS = 6*N*D (train, dense), 6*N_active*D (train, MoE), 2*N*D
 (+cache reads in the memory term) for decode.
 """
@@ -31,16 +31,15 @@ import numpy as np   # noqa: E402
 from repro.configs import ARCHS, SHAPES, get_config  # noqa: E402
 from repro.configs.base import padded_vocab  # noqa: E402
 from repro.launch import build  # noqa: E402
-from repro.launch.mesh import (HBM_BW, ICI_BW, PEAK_FLOPS_BF16,  # noqa: E402
-                               kernel_roofline, make_production_mesh)
+from repro.launch.mesh import (V5E, chip_peaks, kernel_roofline,  # noqa: E402
+                               make_production_mesh)
 from repro.analysis.graph import lift_hlo  # noqa: E402
 from repro.models.transformer import _period, layer_plan  # noqa: E402
 
 
 def _cost_of(built) -> dict:
-    from repro.utils import compat
     compiled = built.lowered.compile()
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     coll = lift_hlo(compiled.as_text())
     mem = compiled.memory_analysis()
     return {
@@ -173,9 +172,10 @@ def roofline_pair(arch: str, shape_name: str, *, chips: int = 256) -> dict:
             # roofline lowers microbatch=1; the production step uses the
             # same total tokens, so per-step cost is identical.
             pass
-    t_compute = cost["flops"] / PEAK_FLOPS_BF16
-    t_memory = cost["bytes"] / HBM_BW
-    t_coll = cost["coll_bytes"] / ICI_BW
+    peaks = chip_peaks(V5E)      # the production mesh's chip
+    t_compute = cost["flops"] / peaks.flops_bf16
+    t_memory = cost["bytes"] / peaks.hbm_bw
+    t_coll = cost["coll_bytes"] / peaks.ici_link_bw
     terms = {"compute_s": t_compute, "memory_s": t_memory,
              "collective_s": t_coll}
     dominant = max(terms, key=terms.get)
@@ -199,11 +199,14 @@ def roofline_pair(arch: str, shape_name: str, *, chips: int = 256) -> dict:
 def kernel_roofline_summary(bench: dict) -> dict:
     """Per-kernel roofline fractions from a BENCH_kernels.json dict:
     every ``model_flops_<cell>`` counter is paired with its
-    ``model_bytes_<cell>`` twin and the cell's measured time, and
-    reported as achieved FLOP/s and bytes/s against the chip peaks.
-    The models are machine-independent (exact-gated in CI); the
-    fractions carry whatever the timing host achieved — interpret-mode
-    CPU numbers in CI, real kernel numbers on TPU."""
+    ``model_bytes_<cell>`` twin and the cell's measured time. Rates and
+    fractions of the chip peaks are reported only when the run's
+    ``device_kind`` has published peaks (``repro.launch.mesh``);
+    otherwise the summary says that they were not measured."""
+    from repro.launch.mesh import CHIP_PEAKS
+
+    kind = bench.get("env", {}).get("device_kind", "unknown")
+    peaks = CHIP_PEAKS.get(kind)
     counters = bench.get("counters", {})
     timings = bench.get("timings_s", {})
     cells = {}
@@ -215,15 +218,19 @@ def kernel_roofline_summary(bench: dict) -> dict:
         t = timings.get(cell)
         if nbytes is None or not t:
             continue
-        cells[cell] = {
-            "time_s": t,
-            "model_flops": float(fl),
-            "model_bytes": float(nbytes),
-            **kernel_roofline(float(fl), float(nbytes), float(t)),
-        }
-    return {"peaks": {"flops_bf16_per_s": PEAK_FLOPS_BF16,
-                      "hbm_bytes_per_s": HBM_BW},
-            "cells": cells}
+        cells[cell] = {"time_s": t, "model_flops": float(fl),
+                       "model_bytes": float(nbytes)}
+        if peaks is not None:
+            cells[cell].update(kernel_roofline(float(fl), float(nbytes),
+                                               float(t), peaks))
+    out = {"device_kind": kind, "cells": cells}
+    if peaks is None:
+        out["notes"] = [f"roofline shares not measured: device kind "
+                        f"{kind!r} has no published peaks"]
+    else:
+        out["peaks"] = {"flops_bf16_per_s": peaks.flops_bf16,
+                        "hbm_bytes_per_s": peaks.hbm_bw}
+    return out
 
 
 def main() -> None:
@@ -249,7 +256,12 @@ def main() -> None:
             os.makedirs(os.path.dirname(out) or ".", exist_ok=True)
         with open(out, "w") as f:
             json.dump(summary, f, indent=1, sort_keys=True)
+        for note in summary.get("notes", ()):
+            print(f"# {note}")
         for cell, rec in summary["cells"].items():
+            if "flops_frac_of_peak" not in rec:
+                print(f"{cell:44s} {rec['time_s'] * 1e6:10.1f} us")
+                continue
             print(f"{cell:44s} {rec['achieved_gflops']:8.3f} GFLOP/s "
                   f"({rec['flops_frac_of_peak']:.2e} of peak)  "
                   f"{rec['achieved_gbps']:8.3f} GB/s "

@@ -32,6 +32,7 @@ from repro.optim import AdamWConfig, adamw_init
 from repro.optim.local_updates import LocalUpdatesConfig, local_updates_round, suggest_H
 from repro.train import make_train_step
 from repro.checkpoint import save_checkpoint
+from repro.utils.cache import enable_compilation_cache
 
 
 def main() -> None:
@@ -59,6 +60,7 @@ def main() -> None:
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compilation_cache()
 
     # fold the deprecated --codec spelling into the --exchange spec
     from repro.core.distributed import ExchangeConfig

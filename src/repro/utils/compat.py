@@ -1,10 +1,9 @@
-"""jax version compatibility shims.
+"""The few jax spellings the codebase shares.
 
-The codebase targets current jax (``jax.shard_map``, ``Mesh`` axis
-types); CI and some dev hosts run older 0.4.x where shard_map lives in
-``jax.experimental`` with a ``check_rep`` kwarg and ``make_mesh`` has no
-``axis_types``. Everything that builds meshes or shard_maps goes through
-here so the support matrix lives in one file.
+Everything that builds meshes or shard_maps, and every Pallas kernel
+entry point, goes through here, so the platform switch and the mesh
+conventions live in one file. The code targets the installed jax
+(``jax>=0.9``): ``jax.shard_map``, ``Mesh`` axis types.
 """
 from __future__ import annotations
 
@@ -25,39 +24,13 @@ def default_interpret(interpret: bool | None) -> bool:
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types where supported."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            tuple(axis_shapes), tuple(axis_names),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(axis_names)))
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
-
-
-def abstract_mesh(axis_shapes, axis_names):
-    """``AbstractMesh`` across the signature change: new jax takes
-    ``(shape, names)``, 0.4.x takes ``((name, size), ...)`` pairs."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(axis_shapes),
-                                         tuple(axis_names))
-    except TypeError:
-        return jax.sharding.AbstractMesh(
-            tuple(zip(axis_names, axis_shapes)))
-
-
-def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` across the return-type change: new
-    jax returns one dict, 0.4.x returns a one-element list of dicts."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        return ca[0] if ca else {}
-    return ca or {}
+    """``jax.make_mesh`` with Auto axis types."""
+    names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names))
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication checking off, on any jax."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    """``jax.shard_map`` with replication checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

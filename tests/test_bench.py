@@ -226,3 +226,47 @@ def test_smoke_tier_end_to_end(tmp_path):
             assert r["comm_bytes_per_round"] == 0
         else:
             assert r["comm_bytes_per_round"] > 0
+
+
+# ------------------------------------------------------------ chip peaks
+def test_chip_peaks_known_kind_and_unknown_raises():
+    from repro.launch.mesh import V5E, chip_peaks, kernel_roofline
+
+    peaks = chip_peaks(V5E)
+    rl = kernel_roofline(197e9, 819e6, 1e-3, peaks)
+    assert rl["flops_frac_of_peak"] == pytest.approx(1.0)
+    assert rl["bw_frac_of_hbm"] == pytest.approx(1.0)
+    for kind in ("cpu", "TPU v4", ""):
+        with pytest.raises(KeyError):
+            chip_peaks(kind)
+
+
+@pytest.mark.parametrize("kind,measured", [("TPU v5 lite", True),
+                                           ("cpu", False)])
+def test_kernel_roofline_summary_only_on_known_chips(tmp_path, kind,
+                                                     measured):
+    """``repro.launch.roofline --kernels`` reports peak fractions only
+    for a device with published peaks, and otherwise says they were
+    not measured (in a subprocess: importing the module fakes 512
+    host devices)."""
+    import os
+    import subprocess
+    import sys
+
+    bench = {"env": {"device_kind": kind},
+             "counters": {"model_flops_k": 1000, "model_bytes_k": 4000},
+             "timings_s": {"k": 1e-6}}
+    src = tmp_path / "BENCH_kernels.json"
+    src.write_text(json.dumps(bench))
+    out = tmp_path / "ROOFLINE_kernels.json"
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"))
+    r = subprocess.run([sys.executable, "-m", "repro.launch.roofline",
+                        "--kernels", str(src), "--out", str(out)],
+                       env=env, capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr
+    summary = json.loads(out.read_text())
+    cell = summary["cells"]["k"]
+    assert cell["model_flops"] == 1000
+    assert ("flops_frac_of_peak" in cell) is measured
+    assert ("notes" in summary) is not measured

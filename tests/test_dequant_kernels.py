@@ -116,6 +116,24 @@ def test_topk_select_bit_identical_to_oracle(L):
     assert float(t_ref) == float(t_ker)
 
 
+@pytest.mark.parametrize("values", ["normal", "ties"])
+def test_topk_select_bit_identical_across_blocks(values):
+    """An update longer than one VMEM block (three grid steps): the
+    running top k merged block by block equals ``lax.top_k`` over the
+    whole vector, including ties whose entries sit in different blocks
+    (the earlier block's index must win)."""
+    L, k = 140_000, 64
+    rng = np.random.default_rng(11)
+    x = (rng.standard_normal(L) if values == "normal"
+         else rng.integers(-3, 4, L))
+    dv = jnp.asarray(x, jnp.float32)
+    mags, idx = jax.lax.top_k(jnp.abs(dv), k)
+    got_v, got_i, got_t = topk_select(dv, k)
+    assert np.array_equal(np.asarray(jnp.take(dv, idx)), np.asarray(got_v))
+    assert np.array_equal(np.asarray(idx), np.asarray(got_i))
+    assert float(mags[k - 1]) == float(got_t)
+
+
 def test_topk_select_breaks_ties_like_the_oracle():
     """Duplicate magnitudes (including +x vs -x) select the lowest
     index first — ``lax.top_k``'s stable order, which the kernel's

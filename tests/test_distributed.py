@@ -390,6 +390,43 @@ print("OK")
 """)
 
 
+def _round_text(make_round, n: int) -> str:
+    """Lowered program text of one CoCoA round on an (m=64, n) problem."""
+    import jax
+
+    from repro.core import CoCoAConfig, CoCoATrainer
+    from repro.data import make_glm_data
+
+    A, b, _ = make_glm_data(m=64, n=n, density=0.3, seed=0)
+    tr = CoCoATrainer(CoCoAConfig(K=4, H=16), A, b)
+    return make_round(tr).lower(*tr.init_state(), jax.random.key(0),
+                                1).as_text()
+
+
+def test_virtual_round_text_independent_of_data_size():
+    """The virtual driver takes the data as an argument of its jitted
+    round: 64x more data (1 MiB at n=4096, which a closure would embed
+    as a constant) leaves the lowered program the same size."""
+    small = _round_text(lambda tr: tr._round_fn, 64)
+    big = _round_text(lambda tr: tr._round_fn, 4096)
+    assert len(big) <= 1.05 * len(small), (len(small), len(big))
+
+
+def test_sharded_round_text_independent_of_data_size():
+    """Same contract for the sharded driver, on 4 faked devices."""
+    _run("""
+import sys
+sys.path.insert(0, %r)
+from test_distributed import _round_text
+from repro.utils.compat import make_mesh
+mesh = make_mesh((4,), ("workers",))
+small = _round_text(lambda tr: tr.build_sharded_round(mesh), 64)
+big = _round_text(lambda tr: tr.build_sharded_round(mesh), 4096)
+assert len(big) <= 1.05 * len(small), (len(small), len(big))
+print("OK")
+""" % os.path.dirname(os.path.abspath(__file__)), ndev=4)
+
+
 def test_cocoa_spark_faithful_extra_collectives():
     _run("""
 import numpy as np, jax, jax.random as jr
@@ -404,7 +441,7 @@ for scheme in ("persistent", "spark_faithful"):
     mesh = make_mesh((8,), ("workers",))
     rf = tr.build_sharded_round(mesh)
     alpha, w = tr.init_state()
-    low = rf.jitted.lower(rf.split_keys(jr.key(0)), alpha, w, 1)
+    low = rf.lower(alpha, w, jr.key(0), 1)
     texts[scheme] = parse_collectives(low.compile().as_text())
 p, s = texts["persistent"], texts["spark_faithful"]
 assert "all-gather" in s.by_kind and "all-gather" not in p.by_kind
@@ -517,8 +554,7 @@ mesh = make_mesh((4,), ("workers",))
 def traffic(tr):
     rf = tr.build_sharded_round(mesh)
     local, shared = tr.init_state()
-    txt = rf.jitted.lower(rf.split_keys(jr.key(0)),
-                          local, shared, 1).compile().as_text()
+    txt = rf.lower(local, shared, jr.key(0), 1).compile().as_text()
     s = parse_collectives(txt)
     return {k: v[1] for k, v in s.by_kind.items()}
 for scheme in COMM_SCHEMES:
@@ -570,8 +606,7 @@ def make(algo, spec):
 def traffic(tr):
     rf = tr.build_sharded_round(mesh)
     local, shared = tr.init_state()
-    txt = rf.jitted.lower(rf.split_keys(jr.key(0)),
-                          local, shared, 1).compile().as_text()
+    txt = rf.lower(local, shared, jr.key(0), 1).compile().as_text()
     return {k: v[1] for k, v in parse_collectives(txt).by_kind.items()}
 CASES = (("cocoa", "persistent/drop:1@2-4"),
          ("cocoa", "compressed:int8/stale:k=2/drop:0@1-2"),
@@ -614,8 +649,7 @@ mesh = make_mesh((4,), ("workers",))
 def hlo(tr):
     rf = tr.build_sharded_round(mesh)
     local, shared = tr.init_state()
-    return rf.jitted.lower(rf.split_keys(jr.key(0)),
-                           local, shared, 1).compile().as_text()
+    return rf.lower(local, shared, jr.key(0), 1).compile().as_text()
 coc = hlo(CoCoATrainer(CoCoAConfig(K=4, H=32), A, b))
 sgd = hlo(MinibatchSGD(SGDConfig(K=4, step_size=0.1), A, b))
 assert re.search(rf"f32\\[{m}\\]\\S* all-reduce", coc), "m-vector all-reduce missing"
@@ -960,7 +994,7 @@ from repro.utils.compat import make_mesh
 mesh = make_mesh((8,), ("workers",))
 rf = tr.build_sharded_round(mesh)
 alpha, w = tr.init_state()
-txt = rf.jitted.lower(rf.split_keys(jr.key(0)), alpha, w, 1).compile().as_text()
+txt = rf.lower(alpha, w, jr.key(0), 1).compile().as_text()
 assert re.search(r"s8\\[[0-9,]+\\][^ ]* all-gather", txt), "int8 all-gather missing"
 h = tr.run_sharded(rounds=25, record_every=25)
 assert h.subopt[-1] < 5e-2, h.subopt

@@ -16,8 +16,7 @@ from repro.models import build_model
 def mesh():
     # AbstractMesh: full production shape without needing 256 devices —
     # the spec functions only read mesh.shape / axis_names.
-    from repro.utils.compat import abstract_mesh
-    return abstract_mesh((16, 16), ("data", "model"))
+    return jax.sharding.AbstractMesh((16, 16), ("data", "model"))
 
 
 def _params(arch):
