@@ -27,7 +27,7 @@ from jax.sharding import Mesh
 
 from repro.core import distributed as dist
 from repro.core.glm import GLMProblem, optimal_objective, primal_objective, suboptimality
-from repro.core.cocoa import CoCoAConfig, CoCoATrainer, History
+from repro.core.cocoa import CoCoAConfig, CoCoATrainer, History, record_loop
 from repro.utils import compat
 
 
@@ -309,29 +309,14 @@ class MinibatchSGD:
     # ------------------------------------------------------------------
     # distributed drivers (row-partitioned, per-worker sampling)
     # ------------------------------------------------------------------
-    def _record_loop(self, round_fn, local, alpha, rounds, record_every,
-                     target_eps, p_star, p_zero) -> History:
-        key = jax.random.key(self.cfg.seed)
+    def _start(self, p_star, p_zero):
+        """The history a call records into and its round-key chain."""
         hist = History(p_star=self.p_star if p_star is None else p_star,
                        p_zero=self.p_zero if p_zero is None else p_zero)
-        last_t = 0
-        for t in range(1, rounds + 1):
-            last_t = t
-            key, sub = jax.random.split(key)
-            local, alpha, primal = round_fn(local, alpha, sub, t)
-            if t % record_every == 0 or t == rounds:
-                p = float(primal)
-                s = suboptimality(p, hist.p_star, hist.p_zero)
-                hist.rounds.append(t)
-                hist.primal.append(p)
-                hist.subopt.append(s)
-                if target_eps is not None and s <= target_eps:
-                    break
-        # stale runs carry one unapplied aggregate; absorb it so the
-        # final iterate reflects every round that was computed
-        alpha = dist.finish_run(round_fn, alpha, last_t)
+        return hist, jax.random.key(self.cfg.seed)
+
+    def _keep_final(self, local, alpha):
         self.alpha_final = np.asarray(alpha)
-        return hist
 
     def run_workers(self, rounds: int, record_every: int = 10,
                     target_eps: float | None = None,
@@ -339,9 +324,11 @@ class MinibatchSGD:
                     p_zero: float | None = None) -> History:
         """K virtual workers (vmap over the worker axis) — same math as
         ``run_sharded`` with the communication mechanics elided."""
-        local, alpha = self.init_state()
-        return self._record_loop(self._round_fn, local, alpha, rounds,
-                                 record_every, target_eps, p_star, p_zero)
+        with jax.profiler.TraceAnnotation("repro.setup"):
+            local, alpha = self.init_state()
+            hist, key = self._start(p_star, p_zero)
+        return record_loop(self._round_fn, local, alpha, hist, key, rounds,
+                           record_every, target_eps, self._keep_final)
 
     def build_sharded_round(self, mesh: Mesh):
         """Distributed round via the generic shard_map driver; K must
@@ -357,9 +344,11 @@ class MinibatchSGD:
                     target_eps: float | None = None,
                     p_star: float | None = None,
                     p_zero: float | None = None) -> History:
-        if mesh is None:
-            mesh = compat.make_mesh((self.cfg.K,), ("workers",))
-        round_fn = self.build_sharded_round(mesh)
-        local, alpha = dist.place_state(mesh, *self.init_state())
-        return self._record_loop(round_fn, local, alpha, rounds,
-                                 record_every, target_eps, p_star, p_zero)
+        with jax.profiler.TraceAnnotation("repro.setup"):
+            if mesh is None:
+                mesh = compat.make_mesh((self.cfg.K,), ("workers",))
+            round_fn = self.build_sharded_round(mesh)
+            local, alpha = dist.place_state(mesh, *self.init_state())
+            hist, key = self._start(p_star, p_zero)
+        return record_loop(round_fn, local, alpha, hist, key, rounds,
+                           record_every, target_eps, self._keep_final)

@@ -167,6 +167,49 @@ class _CoCoARound:
         return self.problem.loss(w_new) + reg_sum
 
 
+def record_loop(round_fn, local, shared, hist: History, key, rounds: int,
+                record_every: int, target_eps: float | None,
+                keep: Callable) -> History:
+    """The record loop every trainer's entry points share.
+
+    Runs ``round_fn(local, shared, key, t)`` for ``t = 1 .. rounds``,
+    reads the round's metric back every ``record_every`` rounds (and
+    after the last) into ``hist``, and stops once its suboptimality is
+    at or below ``target_eps``. A stale run's pending aggregate is then
+    absorbed (``finish_run``) and ``keep(local, shared)`` copies the
+    final iterate to the host.
+
+    Profiling a call (``jax.profiler.trace``) shows these phases as
+    host spans on the device ops' clock: per round a
+    ``repro.round`` step (``step_num = t``) holding ``repro.dispatch``
+    (the key split and the round's enqueue; on a fresh program its
+    trace, lowering and compile) and ``repro.readback`` (the metric's
+    sync and the stop test), then ``repro.finish``. With no profiler
+    running they record nothing.
+    """
+    last_t = 0
+    for t in range(1, rounds + 1):
+        with jax.profiler.StepTraceAnnotation("repro.round", step_num=t):
+            with jax.profiler.TraceAnnotation("repro.dispatch"):
+                key, sub = jax.random.split(key)
+                local, shared, primal = round_fn(local, shared, sub, t)
+            last_t = t
+            if t % record_every == 0 or t == rounds:
+                with jax.profiler.TraceAnnotation("repro.readback"):
+                    p = float(primal)
+                    s = suboptimality(p, hist.p_star, hist.p_zero)
+                    hist.rounds.append(t)
+                    hist.primal.append(p)
+                    hist.subopt.append(s)
+                    if target_eps is not None and s <= target_eps:
+                        break
+    with jax.profiler.TraceAnnotation("repro.finish"):
+        # stale runs carry one unapplied aggregate; absorb it so the
+        # final iterate reflects every round that was computed
+        keep(local, dist.finish_run(round_fn, shared, last_t))
+    return hist
+
+
 class CoCoATrainer:
     """Owns the partitioned data and the jitted round functions."""
 
@@ -241,37 +284,19 @@ class CoCoATrainer:
             local_state_len=self.cfg.K * self.part.n_padded,
             K_live=K_live, backend=self.exchange.backend)
 
-    # ------------------------------------------------------------------
-    # the one record loop both drivers share
-    # ------------------------------------------------------------------
-    def _record_loop(self, round_fn, alpha, w, rounds: int,
-                     record_every: int, target_eps: float | None,
-                     p_star: float | None) -> History:
-        key = jax.random.key(self.cfg.seed)
+    def _start(self, p_star: float | None):
+        """The history a call records into and its round-key chain."""
         hist = History(p_star=self.p_star if p_star is None else p_star,
                        p_zero=self.p_zero)
-        last_t = 0
-        for t in range(rounds):
-            last_t = t + 1
-            key, sub = jax.random.split(key)
-            alpha, w, primal = round_fn(alpha, w, sub, t + 1)
-            if (t + 1) % record_every == 0 or t == rounds - 1:
-                p = float(primal)
-                s = suboptimality(p, hist.p_star, hist.p_zero)
-                hist.rounds.append(t + 1)
-                hist.primal.append(p)
-                hist.subopt.append(s)
-                if target_eps is not None and s <= target_eps:
-                    break
-        # stale runs carry one unapplied aggregate; absorb it so the
-        # final iterate reflects every round that was computed, and
-        # drop the codec-state slot a stateful (ef:) codec carried
-        w = dist.finish_run(round_fn, w, last_t)
+        return hist, jax.random.key(self.cfg.seed)
+
+    def _keep_final(self, alpha, w):
+        """Copy the final iterate to the host, without the codec-state
+        slot a stateful (ef:) codec carried."""
         alpha = dist.unwrap_local_state(self.exchange, alpha)
         self.w_final = np.asarray(w)
         self.alpha_final = part_mod.unpack_alpha(np.asarray(alpha),
                                                  self.part, self.n)
-        return hist
 
     # ------------------------------------------------------------------
     # virtual-worker (vmap) driver
@@ -281,9 +306,11 @@ class CoCoATrainer:
             p_star: float | None = None) -> History:
         """``p_star``, when the caller already knows the optimum, skips
         the host solve behind :attr:`p_star`."""
-        alpha, w = self.init_state()
-        return self._record_loop(self._round_fn, alpha, w, rounds,
-                                 record_every, target_eps, p_star)
+        with jax.profiler.TraceAnnotation("repro.setup"):
+            alpha, w = self.init_state()
+            hist, key = self._start(p_star)
+        return record_loop(self._round_fn, alpha, w, hist, key, rounds,
+                           record_every, target_eps, self._keep_final)
 
     # ------------------------------------------------------------------
     # shard_map driver (real distribution over devices)
@@ -301,12 +328,14 @@ class CoCoATrainer:
                     record_every: int = 1,
                     target_eps: float | None = None,
                     p_star: float | None = None) -> History:
-        if mesh is None:
-            mesh = compat.make_mesh((self.cfg.K,), ("workers",))
-        round_fn = self.build_sharded_round(mesh)
-        alpha, w = dist.place_state(mesh, *self.init_state())
-        return self._record_loop(round_fn, alpha, w, rounds, record_every,
-                                 target_eps, p_star)
+        with jax.profiler.TraceAnnotation("repro.setup"):
+            if mesh is None:
+                mesh = compat.make_mesh((self.cfg.K,), ("workers",))
+            round_fn = self.build_sharded_round(mesh)
+            alpha, w = dist.place_state(mesh, *self.init_state())
+            hist, key = self._start(p_star)
+        return record_loop(round_fn, alpha, w, hist, key, rounds,
+                           record_every, target_eps, self._keep_final)
 
     # ------------------------------------------------------------------
     def objective_of(self, alpha_global: np.ndarray) -> float:

@@ -1021,15 +1021,16 @@ def build_virtual_round(algo: RoundAlgorithm, exchange=None, data=None,
         if xmode.stale:
             shared, queue = shared
         keys = jax.random.split(key, K)
-        if use_map:
-            upd, local_new = lax.map(
-                lambda args: algo.local_step(args[0], args[1], shared,
-                                             args[2], t),
-                (data, local, keys))
-        else:
-            upd, local_new = jax.vmap(
-                lambda d, l, k_: algo.local_step(d, l, shared, k_, t))(
-                    data, local, keys)
+        with jax.named_scope("workers"):
+            if use_map:
+                upd, local_new = lax.map(
+                    lambda args: algo.local_step(args[0], args[1], shared,
+                                                 args[2], t),
+                    (data, local, keys))
+            else:
+                upd, local_new = jax.vmap(
+                    lambda d, l, k_: algo.local_step(d, l, shared, k_, t))(
+                        data, local, keys)
         cstate_in = cstate if stateful else None
         if not membership.empty:
             mask = membership.live_mask(t, K)
@@ -1041,38 +1042,43 @@ def build_virtual_round(algo: RoundAlgorithm, exchange=None, data=None,
                 # a codec fixed point) and frozen below, so it neither
                 # leaks into the aggregate nor decays while absent
                 cstate_in = cstate_in * mask[:, None]
-        if stateful:
-            total, cstate_new = comm.all_reduce_stacked(upd, cstate_in)
-            if not membership.empty:
-                cstate_new = _freeze_dropped(cstate_new, cstate, mask)
-        else:
-            total = comm.all_reduce_stacked(upd)
+        with jax.named_scope("exchange"):
+            if stateful:
+                total, cstate_new = comm.all_reduce_stacked(upd, cstate_in)
+            else:
+                total = comm.all_reduce_stacked(upd)
+        if stateful and not membership.empty:
+            cstate_new = _freeze_dropped(cstate_new, cstate, mask)
         if reweight:
             total = total * (K / jnp.maximum(jnp.sum(mask), 1.0))
-        if xmode.stale:
-            shared_new = _delayed_apply(algo, shared, queue, t, k)
-            shared_out = (shared_new, _queue_push(queue, total))
-            # the metric must be the objective of ONE iterate: pair the
-            # shared state absorbed through round t-1 (the metric-only
-            # absorb of the still-pending aggregates) with the ROUND-t-1
-            # local state (for CoCoA, w = A@alpha - b holds exactly for
-            # that pair). Mixing in the round-t local state produces a
-            # value that is no iterate's objective and can dip below
-            # p_star. Under stale the recorded metric therefore lags
-            # one round — the honest cost of the delayed apply.
-            metric_shared = _absorb_for_metric(algo, shared_new, queue, t, k)
-            metric_local = local
-        else:
-            shared_new = algo.apply_update(shared, total, t)
-            shared_out = shared_new
-            metric_shared = shared_new
-            metric_local = local_new
-        metric_sum = jnp.sum(jax.vmap(
-            lambda d, l: algo.local_metric(d, l, metric_shared))(
-                data, metric_local))
+        with jax.named_scope("apply"):
+            if xmode.stale:
+                shared_new = _delayed_apply(algo, shared, queue, t, k)
+                shared_out = (shared_new, _queue_push(queue, total))
+            else:
+                shared_new = shared_out = algo.apply_update(shared, total, t)
+        with jax.named_scope("metric"):
+            if xmode.stale:
+                # the metric must be the objective of ONE iterate: pair
+                # the shared state absorbed through round t-1 (the
+                # metric-only absorb of the still-pending aggregates)
+                # with the ROUND-t-1 local state (for CoCoA, w =
+                # A@alpha - b holds exactly for that pair). Mixing in
+                # the round-t local state produces a value that is no
+                # iterate's objective and can dip below p_star. Under
+                # stale the recorded metric therefore lags one round —
+                # the honest cost of the delayed apply.
+                metric_shared = _absorb_for_metric(algo, shared_new, queue,
+                                                   t, k)
+                metric_local = local
+            else:
+                metric_shared, metric_local = shared_new, local_new
+            metric_sum = jnp.sum(jax.vmap(
+                lambda d, l: algo.local_metric(d, l, metric_shared))(
+                    data, metric_local))
+            metric = algo.finalize_metric(metric_shared, metric_sum)
         local_out = (local_new, cstate_new) if stateful else local_new
-        return local_out, shared_out, algo.finalize_metric(metric_shared,
-                                                           metric_sum)
+        return local_out, shared_out, metric
 
     def round_fn(local, shared, key, t=1):
         return jitted(data, local, shared, key, t)
@@ -1130,7 +1136,9 @@ def build_sharded_round(algo: RoundAlgorithm, exchange=None, data=None,
         key_k = jax.random.wrap_key_data(keys_sh[0])
         if xmode.stale:
             shared, queue = shared
-        upd, local_new = algo.local_step(data_k, local_k, shared, key_k, t)
+        with jax.named_scope("workers"):
+            upd, local_new = algo.local_step(data_k, local_k, shared, key_k,
+                                             t)
         cstate_in = cstate_k if stateful else None
         if not membership.empty:
             mask = membership.live_mask(t, K)
@@ -1142,33 +1150,39 @@ def build_sharded_round(algo: RoundAlgorithm, exchange=None, data=None,
                 # worker's residual is zeroed before encode and frozen
                 # after — exact-zero wire contribution, no decay
                 cstate_in = cstate_in * mask_k
-        if stateful:
-            total, cstate_new = comm.all_reduce(upd, axis,
-                                                backend=ex.backend,
-                                                state=cstate_in)
-            if not membership.empty:
-                cstate_new = _freeze_dropped(cstate_new, cstate_k, mask_k)
-        else:
-            total = comm.all_reduce(upd, axis, backend=ex.backend)
+        with jax.named_scope("exchange"):
+            if stateful:
+                total, cstate_new = comm.all_reduce(upd, axis,
+                                                    backend=ex.backend,
+                                                    state=cstate_in)
+            else:
+                total = comm.all_reduce(upd, axis, backend=ex.backend)
+        if stateful and not membership.empty:
+            cstate_new = _freeze_dropped(cstate_new, cstate_k, mask_k)
         if reweight:
             total = total * (K / jnp.maximum(jnp.sum(mask), 1.0))
-        if xmode.stale:
-            shared_new = _delayed_apply(algo, shared, queue, t, k)
-            shared_out = (shared_new, _queue_push(queue, total))
-            metric_shared = _absorb_for_metric(algo, shared_new, queue, t, k)
-        else:
-            shared_new = algo.apply_update(shared, total, t)
-            shared_out = shared_new
-            metric_shared = shared_new
-        local_new = comm.roundtrip_local_state(local_new, axis,
-                                               backend=ex.backend)
-        # stale pairs the lagged shared state with the round-t-1 local
-        # state so the metric is a real iterate's objective (see the
-        # virtual driver) — and matches it round for round
-        metric_local = local_k if xmode.stale else local_new
-        metric_sum = lax.psum(algo.local_metric(data_k, metric_local,
-                                                metric_shared), axis)
-        metric = algo.finalize_metric(metric_shared, metric_sum)
+        with jax.named_scope("apply"):
+            if xmode.stale:
+                shared_new = _delayed_apply(algo, shared, queue, t, k)
+                shared_out = (shared_new, _queue_push(queue, total))
+            else:
+                shared_new = shared_out = algo.apply_update(shared, total, t)
+        with jax.named_scope("exchange"):
+            local_new = comm.roundtrip_local_state(local_new, axis,
+                                                   backend=ex.backend)
+        with jax.named_scope("metric"):
+            # stale pairs the lagged shared state with the round-t-1
+            # local state so the metric is a real iterate's objective
+            # (see build_virtual_round) — and matches it round for round
+            if xmode.stale:
+                metric_shared = _absorb_for_metric(algo, shared_new, queue,
+                                                   t, k)
+                metric_local = local_k
+            else:
+                metric_shared, metric_local = shared_new, local_new
+            metric_sum = lax.psum(algo.local_metric(data_k, metric_local,
+                                                    metric_shared), axis)
+            metric = algo.finalize_metric(metric_shared, metric_sum)
         local_out = ((local_new[None], cstate_new[None]) if stateful
                      else local_new[None])
         return local_out, shared_out, metric

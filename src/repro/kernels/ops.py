@@ -31,9 +31,11 @@ def scd_steps_kernel(A_k: jax.Array, col_sq: jax.Array, alpha_k: jax.Array,
     ``h_blk=None`` lets the kernel size its grid block from the VMEM
     budget.
     """
-    cols = jnp.take(A_k, idx, axis=1).T              # (H, m) pre-gather
+    with jax.named_scope("gather"):
+        cols = jnp.take(A_k, idx, axis=1).T          # (H, m) pre-gather
+        col_sq_h = col_sq[idx]
     alpha_new, rho = scd_pallas(
-        cols, col_sq[idx], idx, alpha_k.astype(jnp.float32), w,
+        cols, col_sq_h, idx, alpha_k.astype(jnp.float32), w,
         sigma=float(sigma), lam_eta=float(lam * eta),
         lam_l1=float(lam * (1.0 - eta)), h_blk=h_blk,
         interpret=interpret)
