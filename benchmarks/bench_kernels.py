@@ -28,6 +28,7 @@ from benchmarks import common
 from repro.bench.registry import BenchContext, benchmark
 from repro.bench.timing import TimingPolicy, time_callable
 from repro.comm.codec import get_codec
+from repro.core.partition import block_partition, pack_columns, tile_columns
 from repro.kernels import (decode_mean_int2, decode_mean_int4,
                            decode_mean_int8, decode_stacked_ref,
                            quantize_pack_int2, quantize_pack_int2_ref,
@@ -65,16 +66,18 @@ def run(ctx: BenchContext) -> dict:
     rows, timings, counters = [], {}, {}
     kind = jax.devices()[0].device_kind
     peaks = CHIP_PEAKS.get(kind)
-    # -- tiled SCD: lane-tiled Pallas kernel vs the jnp reference loop.
-    # The rework streams (h_blk, S, 128) column tiles through VMEM, so
-    # the kernel must hold its own against the oracle even in interpret
-    # mode: the smoke tier pins >= 0.9x ref GFLOP/s on the largest-m
-    # shape (the one where the old (1, m) row layout wasted 7/8 of
-    # every f32 sublane tile).
+    # -- tiled SCD: lane-tiled Pallas kernel vs the jnp reference loop,
+    # both on the (n, S, 128) column block. The kernel fetches each
+    # visited (S, 128) column tile into VMEM itself, so it must hold its
+    # own against the oracle even in interpret mode: the smoke tier pins
+    # >= 0.9x ref GFLOP/s on the largest-m shape (the one where a
+    # (1, m) row layout would waste 7/8 of every f32 sublane tile).
     big_m = max(wl.kernel_shapes, key=lambda s: s[0])
     for (m, n, H) in wl.kernel_shapes:
-        A = jnp.asarray(rng.standard_normal((m, n)), jnp.float32)
-        colsq = jnp.sum(A * A, 0)
+        packed, _ = pack_columns(
+            rng.standard_normal((m, n)).astype(np.float32),
+            block_partition(n, 1))
+        (A,), (colsq,) = tile_columns(jnp.asarray(packed))
         alpha = jnp.zeros(n, jnp.float32)
         w = jnp.asarray(rng.standard_normal(m), jnp.float32)
         idx = jnp.asarray(rng.integers(0, n, H), jnp.int32)

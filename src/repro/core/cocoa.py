@@ -228,9 +228,9 @@ class CoCoATrainer:
         else:
             self.part = part_mod.block_partition(n, cfg.K)
         A_st, mask = part_mod.pack_columns(self.A_np, self.part)
-        self.A_st = jnp.asarray(A_st)                       # (K, m, n_pad)
+        # uploaded as packed, laid out on the device: (K, n_pad, S, 128)
+        self.A_st, self.col_sq = part_mod.tile_columns(jnp.asarray(A_st))
         self.mask = jnp.asarray(mask)                       # (K, n_pad)
-        self.col_sq = jnp.sum(self.A_st ** 2, axis=1)       # (K, n_pad)
         self.b = jnp.asarray(self.b_np)
         self._solver = _get_solver(cfg.solver)
         self._algo = _CoCoARound(cfg, self.problem, self._solver)

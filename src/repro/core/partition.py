@@ -7,15 +7,22 @@ Two strategies, mirroring the paper:
                     bin-packing so that sum_i nnz(c_i) is roughly equal
                     per partition.
 
-Both return a permutation + per-worker index sets, and a packer that
-produces the stacked dense (K, m, n_k) tensor used by the virtual-worker
-and shard_map drivers (columns zero-padded to a common width).
+Both return a permutation + per-worker index sets. ``pack_columns``
+stacks the worker blocks on the host as (K, mp, n_pad) (columns and
+rows zero-padded), and ``tile_columns`` lays that stack out once on the
+device as the (K, n_pad, S, 128) column tiles that the virtual-worker
+and shard_map drivers and the SCD solvers read.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+
+from repro.kernels.tiling import LANE
 
 
 @dataclass(frozen=True)
@@ -59,21 +66,41 @@ def partition_imbalance(part: Partition, nnz_per_col: np.ndarray) -> float:
 
 
 def pack_columns(A: np.ndarray, part: Partition) -> tuple[np.ndarray, np.ndarray]:
-    """Stack worker column-blocks into (K, m, n_pad) with zero padding.
+    """Stack worker column-blocks into (K, mp, n_pad) with zero padding:
+    columns to the common width n_pad, rows to mp = ceil(m/128)*128, the
+    lane multiple ``tile_columns`` lays out.
 
     Returns (A_stacked, mask) where mask is (K, n_pad) with 1.0 for real
     columns. Zero-padded columns have zero norm; the SCD solvers guard
     against picking them (update is exactly 0 for an all-zero column, and
-    the sampling distribution masks them out).
+    the sampling distribution masks them out). Zero rows change no dot
+    product or norm, and the solvers drop them from rho.
     """
     m, _ = A.shape
     K, n_pad = part.K, part.n_padded
-    out = np.zeros((K, m, n_pad), dtype=A.dtype)
+    out = np.zeros((K, -(-m // LANE) * LANE, n_pad), dtype=A.dtype)
     mask = np.zeros((K, n_pad), dtype=A.dtype)
     for k, ids in enumerate(part.owned):
-        out[k, :, : len(ids)] = A[:, ids]
+        out[k, :m, : len(ids)] = A[:, ids]
         mask[k, : len(ids)] = 1.0
     return out, mask
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def tile_columns(A_st: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """The packed (K, mp, n_pad) stack on the device -> its
+    (K, n_pad, S, 128) column tiles, S = mp / 128, and their (K, n_pad)
+    squared norms.
+
+    Column j of worker k becomes the contiguous, tile-aligned slab
+    ``[k, j]``: the (S, 128) tile the SCD kernel fetches and computes on.
+    The packed stack is donated, so the tiles can take its buffer: the
+    layout costs one stack of device memory more, not two.
+    """
+    K, mp, n_pad = A_st.shape
+    assert mp % LANE == 0, mp
+    tiles = A_st.reshape(K, mp // LANE, LANE, n_pad).transpose(0, 3, 1, 2)
+    return tiles, jnp.sum(tiles * tiles, axis=(2, 3))
 
 
 def unpack_alpha(alpha_stacked: np.ndarray, part: Partition, n: int) -> np.ndarray:

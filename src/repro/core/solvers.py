@@ -34,6 +34,12 @@ def soft_threshold(z: jax.Array, tau) -> jax.Array:
     return jnp.sign(z) * jnp.maximum(jnp.abs(z) - tau, 0.0)
 
 
+def _column(A_k: jax.Array, j, m: int) -> jax.Array:
+    """Column j, as an m-vector, of a lane-tiled (n_local, S, 128) block."""
+    return lax.dynamic_index_in_dim(A_k, j, axis=0,
+                                    keepdims=False).reshape(-1)[:m]
+
+
 @functools.partial(jax.jit, static_argnames=("unroll",))
 def scd_steps(A_k: jax.Array, col_sq: jax.Array, alpha_k: jax.Array,
               w: jax.Array, idx: jax.Array, *, sigma: float, lam: float,
@@ -41,7 +47,9 @@ def scd_steps(A_k: jax.Array, col_sq: jax.Array, alpha_k: jax.Array,
     """Run len(idx) sequential SCD steps on one worker's column block.
 
     Args:
-      A_k:    (m, n_local) dense local column block (zero-padded cols ok).
+      A_k:    (n_local, S, 128) the local column block, lane-tiled by
+              ``partition.tile_columns``: column j is ``A_k[j]`` with its
+              m rows zero-padded to S*128 (zero-padded cols ok).
       col_sq: (n_local,) squared column norms of A_k.
       alpha_k:(n_local,) local coordinates of alpha.
       w:      (m,) shared residual vector  w = A alpha - b  at round start.
@@ -58,7 +66,7 @@ def scd_steps(A_k: jax.Array, col_sq: jax.Array, alpha_k: jax.Array,
     def body(i, carry):
         alpha, rho = carry
         j = idx[i]
-        c = lax.dynamic_index_in_dim(A_k, j, axis=1, keepdims=False)
+        c = _column(A_k, j, w.shape[0])
         csq = col_sq[j]
         a = alpha[j]
         denom = sigma * csq + lam_eta
@@ -90,7 +98,7 @@ def scd_steps_fixed_point(A_k, col_sq, alpha_k, w, idx, *, sigma, lam, eta):
     def body(i, carry):
         alpha, dv = carry
         j = idx[i]
-        c = lax.dynamic_index_in_dim(A_k, j, axis=1, keepdims=False)
+        c = _column(A_k, j, w.shape[0])
         csq = col_sq[j]
         a = alpha[j]
         denom = sigma * csq + lam_eta

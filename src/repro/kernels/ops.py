@@ -2,10 +2,12 @@
 
 ``scd_steps_kernel`` matches the contract of the pure-jnp oracle
 ``repro.kernels.ref.scd_steps_ref`` exactly, so the two are drop-in
-interchangeable as CoCoA local solvers (``CoCoAConfig.solver``). The
-wrapper's only job is the one XLA gather that turns the random-access
-column visits into the dense (H, m) stream the kernel pipelines;
-padding, lane tiling and block sizing all live in ``scd_pallas``.
+interchangeable as CoCoA local solvers (``CoCoAConfig.solver``). Both
+take the worker's block in the lane-tiled ``(n_local, S, 128)`` layout
+of ``partition.tile_columns``. The kernel fetches each visited column
+from that block itself (an index-driven DMA, see ``repro.kernels.scd``),
+so the wrapper only picks out the visited columns' squared norms and
+turns the kernel's residual into the update.
 """
 from __future__ import annotations
 
@@ -18,26 +20,19 @@ from repro.kernels.scd import scd_pallas
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("sigma", "lam", "eta", "h_blk", "interpret"))
+                   static_argnames=("sigma", "lam", "eta", "interpret"))
 def scd_steps_kernel(A_k: jax.Array, col_sq: jax.Array, alpha_k: jax.Array,
                      w: jax.Array, idx: jax.Array, *, sigma: float,
-                     lam: float, eta: float, h_blk: int | None = None,
-                     interpret: bool | None = None):
+                     lam: float, eta: float, interpret: bool | None = None):
     """H SCD steps on one worker's column block via the Pallas kernel.
 
     Same signature/returns as ``repro.core.solvers.scd_steps``:
-      A_k (m, n_local), col_sq (n_local,), alpha_k (n_local,), w (m,),
-      idx (H,) int32  ->  (delta_v (m,), alpha_new (n_local,)).
-    ``h_blk=None`` lets the kernel size its grid block from the VMEM
-    budget.
+      A_k (n_local, S, 128), col_sq (n_local,), alpha_k (n_local,),
+      w (m,), idx (H,) int32  ->  (delta_v (m,), alpha_new (n_local,)).
     """
-    with jax.named_scope("gather"):
-        cols = jnp.take(A_k, idx, axis=1).T          # (H, m) pre-gather
-        col_sq_h = col_sq[idx]
     alpha_new, rho = scd_pallas(
-        cols, col_sq_h, idx, alpha_k.astype(jnp.float32), w,
+        A_k, col_sq[idx], idx, alpha_k.astype(jnp.float32), w,
         sigma=float(sigma), lam_eta=float(lam * eta),
-        lam_l1=float(lam * (1.0 - eta)), h_blk=h_blk,
-        interpret=interpret)
+        lam_l1=float(lam * (1.0 - eta)), interpret=interpret)
     delta_v = (rho - w) / jnp.asarray(sigma, rho.dtype)
     return delta_v.astype(w.dtype), alpha_new.astype(alpha_k.dtype)

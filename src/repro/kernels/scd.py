@@ -2,24 +2,31 @@
 
 This is the TPU-native analogue of the paper's "offload the hot loop to
 an optimized C++ module": the H sequential coordinate-descent steps run
-entirely out of VMEM, with the per-step column data streamed
-HBM -> VMEM by the Pallas pipeline.
+entirely out of VMEM, and the kernel fetches each visited column from
+the worker's block in HBM itself.
 
 TPU adaptation (vs the CPU/C++ original):
-  * SCD gathers one column c_j per step. Random-access gathers from HBM
-    inside a TPU kernel would serialize on DMA latency, so the caller
-    pre-gathers the H visited columns into a dense (H, m) matrix with a
-    single XLA gather; the kernel then *streams* that matrix through
-    VMEM in (h_blk, S, m_blk) tiles via BlockSpec — sequential-friendly
-    DMA, double-buffered by the Pallas pipeline.
-  * The m dimension is LANE-TILED: rho and each streamed column live as
-    (S, m_blk) = (ceil(m/128), 128) 2-D tiles instead of a single
-    (1, m) row. A (1, m) row occupies one sublane of every (8, 128)
-    f32 register tile — 7/8 of the VPU issue width wasted; the (S, 128)
-    layout packs m across sublanes so the per-step dot and the rho
-    update run at full width. rho is the kernel's resident VMEM f32
-    accumulator (constant index_map), exactly the paper's "persistent
-    local memory" idea pushed down the memory hierarchy.
+  * The worker's block is stored column-major and LANE-TILED:
+    ``(n_local, S, 128)`` with S = ceil(m/128) (``partition.tile_columns``
+    lays the stack out so). Column j is one contiguous, tile-aligned
+    (S, 128) slab ``A[j]``, m zero-padded to S*128 rows; a zero row
+    changes neither a dot product nor rho. rho and each column live as
+    (S, 128) tiles instead of a single (1, m) row, which would occupy
+    one sublane of every (8, 128) f32 register tile and waste 7/8 of
+    the VPU issue width; rho is the kernel's resident VMEM f32
+    accumulator, exactly the paper's "persistent local memory" idea
+    pushed down the memory hierarchy.
+  * Index-driven fetch. The coordinate stream is prefetched into SMEM,
+    so the kernel knows every column it will visit: at step s it starts
+    the DMA of column ``idx[s + NBUF - 1]`` into a ring of NBUF column
+    buffers, then waits for column ``idx[s]`` and computes with it. Each
+    fetch is one contiguous copy of 4*S*128 bytes, so it runs at HBM
+    bandwidth and hides behind the steps before it. Only the H visited
+    columns are read, and no (H, m) column matrix is built outside the
+    kernel.
+  * VMEM holds the column ring, NBUF tiles, plus w and rho, one tile
+    each: (NBUF + 2) * 512 * S bytes whatever H is, 3.75 MiB at
+    m = 196,608 and 7.6 MiB at m = 400,000.
   * The per-step scalars — the coordinate index, sigma*||c_j||^2,
     1/denom and the soft-threshold level lam_l1/denom — are precomputed
     VECTORIZED outside the kernel and prefetched whole into SMEM, so
@@ -28,18 +35,13 @@ TPU adaptation (vs the CPU/C++ original):
     indexed ``alpha[j]`` read and store are scalar-unit work, and the
     TPU cannot store a scalar to VMEM. SMEM therefore holds
     4 * H + 2 * n_local words.
-  * ``h_blk`` is picked from a VMEM budget (``_auto_h_blk``) when not
-    given: the double-buffered column stream is the dominant tenant, so
-    h_blk ~ budget / (2 * S * 128 * 4), clamped to [8, 512].
-  * H is padded to a multiple of h_blk with csq = 0 tail steps — exact
-    no-ops by construction (the ``scsq > 0`` guard restores alpha and
-    the zero column leaves rho untouched), replacing the former hard
-    ``H % h_blk == 0`` requirement.
+  * All H steps run in one kernel invocation, as two loops: the first
+    H - (NBUF - 1) steps each start a fetch, the last NBUF - 1 have
+    nothing left to fetch. Any H >= 1 works with no padded steps.
 
-The grid is sequential on TPU, which the state carried in VMEM and
-SMEM relies on. Runs compiled on TPU and in interpret mode everywhere
-else (same ``compat.default_interpret`` convention as the
-quantize/decode kernels).
+Runs compiled on TPU and in interpret mode everywhere else (same
+``compat.default_interpret`` convention as the quantize/decode
+kernels).
 """
 from __future__ import annotations
 
@@ -54,121 +56,116 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.tiling import LANE as _LANE
 from repro.utils import compat
 
-_VMEM_BUDGET = 4 * 1024 * 1024  # bytes allotted to the column stream
+NBUF = 3  # column buffers in the fetch ring: NBUF - 1 fetches ahead
 
 
-def _auto_h_blk(S: int) -> int:
-    """Steps per grid block from the VMEM budget: the double-buffered
-    f32 column stream (2 * h_blk * S * 128 * 4 bytes) is the dominant
-    tenant; clamp to [8, 512] and round down to a sublane multiple."""
-    h = _VMEM_BUDGET // (2 * S * _LANE * 4)
-    return max(8, min(512, (h // 8) * 8))
-
-
-def _scd_kernel(sigma: float, h_blk: int, idx_ref, scsq_ref, dinv_ref,
-                thr_ref, cols_ref, alpha_in_ref, w_ref, alpha_ref, rho_ref):
-    """One grid step: h_blk sequential SCD updates on the kernel state.
+def _scd_kernel(sigma: float, idx_ref, scsq_ref, dinv_ref, thr_ref, A_hbm,
+                alpha_in_ref, w_ref, alpha_ref, rho_ref, cols, sems):
+    """All H = idx.shape[0] sequential SCD updates on the kernel state.
 
     The per-step scalars and the alpha coordinates live in SMEM (scalar
     reads and the dynamically indexed ``alpha[j]`` store run on the
-    scalar unit); the column stream and the rho accumulator are
+    scalar unit); the fetched columns and the rho accumulator are
     (S, 128) VMEM tiles."""
-    i = pl.program_id(0)
-    base = i * h_blk
+    H = idx_ref.shape[0]
 
-    @pl.when(i == 0)
-    def _init():
-        def copy(j, _):
-            alpha_ref[j] = alpha_in_ref[j]
-            return 0
-        lax.fori_loop(0, alpha_ref.shape[0], copy, 0)
-        rho_ref[...] = w_ref[...].astype(jnp.float32)
+    def fetch(step):
+        slot = step % NBUF
+        return pltpu.make_async_copy(A_hbm.at[idx_ref[step]], cols.at[slot],
+                                     sems.at[slot])
 
-    def body(s, _):
-        j = idx_ref[base + s]
-        c = cols_ref[s].astype(jnp.float32)             # (S, m_blk)
-        scsq = scsq_ref[base + s]                       # sigma*||c_j||^2
+    for step in range(min(NBUF - 1, H)):
+        fetch(step).start()
+
+    def copy(j, _):
+        alpha_ref[j] = alpha_in_ref[j]
+        return 0
+    lax.fori_loop(0, alpha_ref.shape[0], copy, 0)
+    rho_ref[...] = w_ref[...]
+
+    def body(prefetch, step, _):
+        if prefetch:
+            fetch(step + NBUF - 1).start()
+        fetch(step).wait()
+        j = idx_ref[step]
+        c = cols[step % NBUF].astype(jnp.float32)      # (S, 128)
+        scsq = scsq_ref[step]                           # sigma*||c_j||^2
         a = alpha_ref[j]
-        rho = rho_ref[...]                              # (S, m_blk)
-        z_tilde = (scsq * a - jnp.sum(rho * c)) * dinv_ref[base + s]
+        rho = rho_ref[...]                              # (S, 128)
+        z_tilde = (scsq * a - jnp.sum(rho * c)) * dinv_ref[step]
         z = jnp.sign(z_tilde) * jnp.maximum(
-            jnp.abs(z_tilde) - thr_ref[base + s], 0.0)
+            jnp.abs(z_tilde) - thr_ref[step], 0.0)
         z = jnp.where(scsq > 0, z, a)                   # padded/zero col
         alpha_ref[j] = z
         rho_ref[...] = rho + (sigma * (z - a)) * c
         return 0
 
-    lax.fori_loop(0, h_blk, body, 0)
+    # the steps whose column NBUF - 1 ahead exists prefetch it; the last
+    # NBUF - 1 steps have nothing left to fetch
+    n_ahead = max(H - (NBUF - 1), 0)
+    lax.fori_loop(0, n_ahead, functools.partial(body, True), 0)
+    lax.fori_loop(n_ahead, H, functools.partial(body, False), 0)
 
 
 @functools.partial(jax.jit, static_argnames=("sigma", "lam_eta", "lam_l1",
-                                             "h_blk", "interpret"))
-def scd_pallas(cols: jax.Array, csq: jax.Array, idx: jax.Array,
+                                             "interpret"))
+def scd_pallas(A: jax.Array, csq: jax.Array, idx: jax.Array,
                alpha: jax.Array, w: jax.Array, *, sigma: float,
-               lam_eta: float, lam_l1: float, h_blk: int | None = None,
-               interpret: bool | None = None
+               lam_eta: float, lam_l1: float, interpret: bool | None = None
                ) -> tuple[jax.Array, jax.Array]:
-    """Run H = cols.shape[0] SCD steps (any H >= 1; the tail is padded
-    with exact no-op steps).
+    """Run H = idx.shape[0] SCD steps (any H >= 1), fetching column
+    ``A[idx[s]]`` at step s.
 
     Args:
-      cols:  (H, m) pre-gathered columns, streaming dtype (f32/bf16).
-      csq:   (H,) squared norms of the gathered columns.
+      A:     (n_local, S, 128) the worker's lane-tiled column block,
+             S = ceil(m / 128), rows past m zero.
+      csq:   (H,) squared norms of the visited columns.
       idx:   (H,) int32 local coordinate index per step.
       alpha: (n_local,) f32 local coordinates.
       w:     (m,) round-start shared residual, f32.
-      h_blk: steps per grid block; ``None`` picks it from the VMEM
-             budget via ``_auto_h_blk``.
     Returns:
       (alpha_new (n_local,) f32, rho (m,) f32).
     """
     interpret = compat.default_interpret(interpret)
-    H, m = cols.shape
+    n_local, S, lane = A.shape
+    (H,), m = idx.shape, w.shape[0]
     assert H >= 1, H
-    n_local = alpha.shape[0]
-    S = -(-m // _LANE)
+    assert lane == _LANE and S == -(-m // _LANE), (A.shape, m)
+    assert alpha.shape == (n_local,), (alpha.shape, n_local)
     mp = S * _LANE
-    if h_blk is None:
-        h_blk = _auto_h_blk(S)
-    h_blk = max(1, min(h_blk, -(-H // 8) * 8))
-    Hp = -(-H // h_blk) * h_blk
 
-    cols_p = jnp.pad(cols, ((0, Hp - H), (0, mp - m)))
-    cols3 = cols_p.reshape(Hp, S, _LANE)
-    idx_p = jnp.pad(idx.astype(jnp.int32), (0, Hp - H))
-    csq_p = jnp.pad(csq.astype(jnp.float32), (0, Hp - H))
     # per-step scalars, vectorized out of the serial loop: the kernel
-    # body carries no divides (padded steps hit denom = lam_eta, which
+    # body carries no divides (a zero column hits denom = lam_eta, which
     # is 0 for pure-l1 problems -> inf/NaN, discarded by the scsq > 0
-    # guard exactly like the zero-column case)
-    scsq = jnp.float32(sigma) * csq_p
+    # guard)
+    scsq = jnp.float32(sigma) * csq.astype(jnp.float32)
     dinv = 1.0 / (scsq + jnp.float32(lam_eta))
     thr = jnp.float32(lam_l1) * dinv
     w3 = jnp.pad(w.astype(jnp.float32), (0, mp - m)).reshape(S, _LANE)
 
-    kernel = functools.partial(_scd_kernel, float(sigma), h_blk)
+    kernel = functools.partial(_scd_kernel, float(sigma))
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    tile = pl.BlockSpec(memory_space=pltpu.VMEM)          # whole, one copy
     alpha_out, rho = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            # idx, sigma*csq, 1/denom, threshold: whole (Hp,) in SMEM
+            # idx, sigma*csq, 1/denom, threshold: whole (H,) in SMEM
             num_scalar_prefetch=4,
-            grid=(Hp // h_blk,),
+            grid=(1,),
             in_specs=[
-                pl.BlockSpec((h_blk, S, _LANE),
-                             lambda i, *_: (i, 0, 0)),    # cols stream
+                pl.BlockSpec(memory_space=pl.ANY),        # A, left in HBM
                 smem,                                     # alpha in
-                pl.BlockSpec((S, _LANE), lambda i, *_: (0, 0)),  # w
+                tile,                                     # w
             ],
-            out_specs=[
-                smem,                                     # alpha out
-                pl.BlockSpec((S, _LANE), lambda i, *_: (0, 0)),  # rho
-            ]),
+            out_specs=[smem, tile],                       # alpha, rho
+            scratch_shapes=[pltpu.VMEM((NBUF, S, _LANE), A.dtype),
+                            pltpu.SemaphoreType.DMA((NBUF,))]),
         out_shape=[
             jax.ShapeDtypeStruct((n_local,), jnp.float32),
             jax.ShapeDtypeStruct((S, _LANE), jnp.float32),
         ],
         interpret=interpret,
         name="scd",
-    )(idx_p, scsq, dinv, thr, cols3, alpha.astype(jnp.float32), w3)
+    )(idx.astype(jnp.int32), scsq, dinv, thr, A,
+      alpha.astype(jnp.float32), w3)
     return alpha_out, rho.reshape(mp)[:m]

@@ -5,7 +5,10 @@ described, not attached: the TPU compiler installed with jax refuses
 here what the chip would refuse (a scalar stored to VMEM, a kernel
 over its scoped VMEM), at no chip time. Nothing runs, so these say
 nothing about results or speed. Sizes are the ones ``chip_smoke.py``
-runs: m = 65,536, K = 8 workers, n_local = H = 1,024.
+runs: m = 65,536, K = 8 workers, n_local = H = 1,024; the round guards
+also compile the benchmark cells' rounds (m = 196,608; one chip with
+K = 8, n_local = 250, and a 2x2 mesh with K = 4, n_local = 500), and
+the SCD kernel is compiled at epsilon's full 400,000 rows.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library, so describing it
@@ -14,6 +17,8 @@ while test files are collected would break the other test workers.
 from __future__ import annotations
 
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,15 +26,16 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 M, K, N_LOCAL, H = 65_536, 8, 1_024, 1_024
+S = M // 128                 # rows of 128 lanes in a lane-tiled column
 L = M                        # the exchanged update is the m-vector
 TOPK_K = 656                 # topk(r=0.01) of L
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """One described v5e chip, with JAX's persistent compilation cache
-    off: a compile for a described chip is written to the cache but
-    cannot be read back without one."""
+def v5e():
+    """A described 2x2 v5e topology, with JAX's persistent compilation
+    cache off: a compile for a described chip is written to the cache
+    but cannot be read back without one."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -43,9 +49,15 @@ def one_chip():
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", enabled)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    """One chip of the described topology."""
+    return SingleDeviceSharding(v5e.devices[0])
 
 
 def _compile(fn, *shapes) -> str:
@@ -62,11 +74,28 @@ def test_scd_kernel_compiles(one_chip):
     from repro.kernels.scd import scd_pallas
 
     s = functools.partial(_shape, one_chip)
-    _compile(lambda c, q, i, a, w: scd_pallas(
-        c, q, i, a, w, sigma=float(K), lam_eta=1.0, lam_l1=0.0,
+    _compile(lambda A, q, i, a, w: scd_pallas(
+        A, q, i, a, w, sigma=float(K), lam_eta=1.0, lam_l1=0.0,
         interpret=False),
-        s((H, M), jnp.float32), s((H,), jnp.float32), s((H,), jnp.int32),
-        s((N_LOCAL,), jnp.float32), s((M,), jnp.float32))
+        s((N_LOCAL, S, 128), jnp.float32), s((H,), jnp.float32),
+        s((H,), jnp.int32), s((N_LOCAL,), jnp.float32),
+        s((M,), jnp.float32))
+
+
+def test_scd_kernel_compiles_at_full_epsilon_rows(one_chip):
+    """epsilon's published 400,000 rows (S = 3,125 lane rows a column):
+    the kernel's VMEM is its column ring plus w and rho whatever H is,
+    so a worker of n_local = H = 250 fits the chip's scoped VMEM."""
+    from repro.kernels.scd import scd_pallas
+
+    m, n_local = 400_000, 250
+    s = functools.partial(_shape, one_chip)
+    _compile(lambda A, q, i, a, w: scd_pallas(
+        A, q, i, a, w, sigma=float(K), lam_eta=1.0, lam_l1=0.0,
+        interpret=False),
+        s((n_local, m // 128, 128), jnp.float32),
+        s((n_local,), jnp.float32), s((n_local,), jnp.int32),
+        s((n_local,), jnp.float32), s((m,), jnp.float32))
 
 
 @pytest.mark.parametrize("codec", ["int8", "int4", "int2"])
@@ -105,6 +134,28 @@ def test_topk_select_compiles(one_chip):
              _shape(one_chip, (L,), jnp.float32))
 
 
+def _virtual_round(sharding, exchange, m, n_local, H_):
+    """Compiled HLO text of a K-worker virtual-driver CoCoA round on the
+    lane-tiled (K, n_local, m / 128, 128) stack, with the kernel
+    dispatch steered to the chip path."""
+    from repro.core import distributed as dist
+    from repro.core.cocoa import CoCoAConfig, _CoCoARound, _get_solver
+    from repro.core.glm import GLMProblem
+
+    cfg = CoCoAConfig(K=K, H=H_, solver="scd_kernel", exchange=exchange,
+                      partitioner="block")
+    algo = _CoCoARound(cfg, GLMProblem(), _get_solver(cfg.solver))
+    s = functools.partial(_shape, sharding)
+    data = (s((K, n_local, m // 128, 128), jnp.float32),
+            s((K, n_local), jnp.float32), s((K, n_local), jnp.float32))
+    rf = dist.build_virtual_round(algo, cfg.exchange, data, K=K,
+                                  use_map=True)
+    key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                               sharding=sharding)
+    return _compile(rf.jitted, data, s((K, n_local), jnp.float32),
+                    s((m,), jnp.float32), key, s((), jnp.int32))
+
+
 @pytest.mark.parametrize("exchange,kernels", [
     ("persistent", {"scd"}),
     ("compressed:int8", {"scd", "quantize_pack_int8",
@@ -115,21 +166,89 @@ def test_cocoa_round_compiles_with_kernels(one_chip, monkeypatch,
     kernel dispatch steered to the chip path: every main-path kernel is
     compiled into the round, none is left to the jnp oracle."""
     from repro.analysis.graph import pallas_kernels
+    from repro.utils import compat
+
+    monkeypatch.setattr(compat, "on_tpu", lambda: True)
+    text = _virtual_round(one_chip, exchange, M, N_LOCAL, H)
+    assert kernels <= pallas_kernels(text)
+
+
+# ops that move no data of their own: control flow, tuples, views
+_NO_DATA = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
+            "conditional", "call"}
+
+
+def _block_sized(text: str, elements: int) -> list[str]:
+    """Instructions outside fusion bodies and outside the Pallas kernel
+    whose result holds an f32 array of at least ``elements``."""
+    fused = set(re.findall(r"calls=%?([\w.\-]+)", text))
+    comp, out = None, []
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{\s*$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        inst = re.match(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.+?) ([\w\-]+)\(",
+                        line)
+        if (not inst or comp in fused or inst.group(3) in _NO_DATA
+                or "tpu_custom_call" in line):
+            continue
+        dims = re.findall(r"f32\[([\d,]+)\]", inst.group(2))
+        if any(math.prod(map(int, d.split(","))) >= elements for d in dims):
+            out.append(inst.group(1))
+    return out
+
+
+@pytest.mark.parametrize("H_", [250, 16])
+def test_cell_round_copies_no_block_around_scd(one_chip, monkeypatch, H_):
+    """The one-chip benchmark cells' round (K = 8, m = 196,608,
+    n_local = 250): the SCD kernel fetches its visited columns from the
+    stack itself, so outside the kernel nothing of half a worker's block
+    or more is written, but for at most the one per-worker slice that
+    ``lax.map`` takes. A round that gathers the (H, m) columns with XLA
+    relays the block out several times over."""
+    from repro.analysis.graph import pallas_kernels
+    from repro.utils import compat
+
+    monkeypatch.setattr(compat, "on_tpu", lambda: True)
+    m, n_local = 196_608, 250
+    text = _virtual_round(one_chip, "persistent", m, n_local, H_)
+    assert "scd" in pallas_kernels(text)
+    big = _block_sized(text, m * n_local // 2)
+    assert len(big) <= 1, big
+
+
+def test_four_chip_cell_round_copies_no_block_around_scd(v5e, monkeypatch):
+    """The four-chip benchmark cell's sharded round on a 2x2 mesh
+    (K = 4, one worker a chip, m = 196,608, n_local = H = 500): each
+    chip's kernel fetches its columns from its shard of the stack, so
+    outside the kernel nothing of half a worker's block or more is
+    written, and the update is summed by one all-reduce."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.analysis.graph import pallas_kernels
     from repro.core import distributed as dist
     from repro.core.cocoa import CoCoAConfig, _CoCoARound, _get_solver
     from repro.core.glm import GLMProblem
     from repro.utils import compat
 
     monkeypatch.setattr(compat, "on_tpu", lambda: True)
-    cfg = CoCoAConfig(K=K, H=H, solver="scd_kernel", exchange=exchange)
+    K4, m, n_local = 4, 196_608, 500
+    mesh = Mesh(np.array(v5e.devices[:K4]), ("workers",))
+    part, rep = NamedSharding(mesh, P("workers")), NamedSharding(mesh, P())
+    cfg = CoCoAConfig(K=K4, H=n_local, solver="scd_kernel",
+                      exchange="persistent", partitioner="block")
     algo = _CoCoARound(cfg, GLMProblem(), _get_solver(cfg.solver))
-    s = functools.partial(_shape, one_chip)
-    data = (s((K, M, N_LOCAL), jnp.float32), s((K, N_LOCAL), jnp.float32),
-            s((K, N_LOCAL), jnp.float32))
-    rf = dist.build_virtual_round(algo, cfg.exchange, data, K=K,
-                                  use_map=True)
-    key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
-                               sharding=one_chip)
-    text = _compile(rf.jitted, data, s((K, N_LOCAL), jnp.float32),
-                    s((M,), jnp.float32), key, s((), jnp.int32))
-    assert kernels <= pallas_kernels(text)
+    data = (_shape(part, (K4, n_local, m // 128, 128), jnp.float32),
+            _shape(part, (K4, n_local), jnp.float32),
+            _shape(part, (K4, n_local), jnp.float32))
+    rf = dist.build_sharded_round(algo, cfg.exchange, data, mesh)
+    text = _compile(rf.jitted, data, _shape(part, (K4, 2), jnp.uint32),
+                    _shape(part, (K4, n_local), jnp.float32),
+                    _shape(rep, (m,), jnp.float32),
+                    _shape(rep, (), jnp.int32))
+    assert "scd" in pallas_kernels(text)
+    assert "all-reduce" in text
+    big = _block_sized(text, m * n_local // 2)
+    assert len(big) <= 1, big

@@ -390,6 +390,29 @@ print("OK")
 """)
 
 
+def test_cocoa_kernel_solver_matches_ref_both_drivers():
+    """The Pallas solver on the lane-tiled stack (m = 200, not a
+    multiple of 128): ``run`` and ``run_sharded`` record the same
+    history, and it is ``scd_ref``'s, round by round."""
+    _run("""
+import numpy as np
+from repro.data import make_glm_data
+from repro.core import CoCoAConfig, CoCoATrainer
+A, b, _ = make_glm_data(m=200, n=96, density=0.3, seed=4)
+hists = {}
+for solver in ("scd_kernel", "scd_ref"):
+    cfg = CoCoAConfig(K=4, H=24, solver=solver, seed=5)
+    hists[solver, "run"] = CoCoATrainer(cfg, A, b).run(rounds=6)
+    hists[solver, "run_sharded"] = CoCoATrainer(cfg, A, b).run_sharded(
+        rounds=6)
+ref = hists["scd_ref", "run"].primal
+for key, h in hists.items():
+    assert h.rounds == list(range(1, 7)), (key, h.rounds)
+    np.testing.assert_allclose(h.primal, ref, rtol=1e-4, err_msg=str(key))
+print("OK")
+""", ndev=4)
+
+
 def _round_text(make_round, n: int) -> str:
     """Lowered program text of one CoCoA round on an (m=64, n) problem."""
     import jax

@@ -5,8 +5,8 @@ A call of ``run`` / ``run_sharded`` (CoCoA) or ``run_workers`` /
 ``run_sharded`` (mini-batch SGD) traced with ``jax.profiler.trace``
 shows ``repro.setup``, then per round a ``repro.round`` step holding
 ``repro.dispatch`` and ``repro.readback``, then ``repro.finish``. The
-compiled rounds name their parts ``workers``, ``gather``, ``exchange``,
-``apply`` and ``metric`` in their HLO ``op_name`` metadata.
+compiled rounds name their parts ``workers``, ``exchange``, ``apply``
+and ``metric`` in their HLO ``op_name`` metadata.
 """
 from __future__ import annotations
 
@@ -29,7 +29,7 @@ from repro.core import (CoCoAConfig, CoCoATrainer, MinibatchSGD,  # noqa: E402
                         SGDConfig)
 from repro.utils import compat  # noqa: E402
 
-SCOPES = ("workers", "gather", "exchange", "apply", "metric")
+SCOPES = ("workers", "exchange", "apply", "metric")
 READERS = ("readback_idle_ms_per_round", "dispatch_idle_ms_per_round",
            "call_idle_ms_per_solve")
 
@@ -143,7 +143,8 @@ def op_names(round_fn, local, shared) -> set:
 def test_round_programs_carry_named_scopes(layout, algorithm):
     """Each part of a compiled round keeps its scope in the HLO
     metadata a device trace shows: the same names in both layouts. The
-    column gather belongs to the SCD kernel's wrapper (CoCoA)."""
+    SCD kernel fetches its own columns (CoCoA), so no ``gather`` scope
+    is left around it."""
     K = 2 if layout == "virtual" else 1
     trainer = cocoa(K) if algorithm == "cocoa" else sgd(K)
     local, shared = trainer.init_state()
@@ -152,12 +153,9 @@ def test_round_programs_carry_named_scopes(layout, algorithm):
     else:
         round_fn = trainer.build_sharded_round(one_device_mesh())
     names = op_names(round_fn, local, shared)
-    scopes = SCOPES if algorithm == "cocoa" else \
-        tuple(s for s in SCOPES if s != "gather")
-    for scope in scopes:
+    for scope in SCOPES:
         assert any(f"/{scope}/" in n for n in names), (scope, sorted(names))
-    gathers = [n for n in names if "/gather/" in n]
-    assert all("/workers/" in n for n in gathers)
+    assert not [n for n in names if "/gather/" in n]
 
 
 # ---------------------------------------------------------------------------
