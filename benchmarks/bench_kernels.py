@@ -28,7 +28,7 @@ from benchmarks import common
 from repro.bench.registry import BenchContext, benchmark
 from repro.bench.timing import TimingPolicy, time_callable
 from repro.comm.codec import get_codec
-from repro.core.partition import block_partition, pack_columns, tile_columns
+from repro.core.partition import block_partition, column_norms, pack_columns
 from repro.kernels import (decode_mean_int2, decode_mean_int4,
                            decode_mean_int8, decode_stacked_ref,
                            quantize_pack_int2, quantize_pack_int2_ref,
@@ -77,7 +77,8 @@ def run(ctx: BenchContext) -> dict:
         packed, _ = pack_columns(
             rng.standard_normal((m, n)).astype(np.float32),
             block_partition(n, 1))
-        (A,), (colsq,) = tile_columns(jnp.asarray(packed))
+        A = jnp.asarray(packed[0])
+        colsq = column_norms(A[None])[0]
         alpha = jnp.zeros(n, jnp.float32)
         w = jnp.asarray(rng.standard_normal(m), jnp.float32)
         idx = jnp.asarray(rng.integers(0, n, H), jnp.int32)

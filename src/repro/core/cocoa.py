@@ -228,8 +228,10 @@ class CoCoATrainer:
         else:
             self.part = part_mod.block_partition(n, cfg.K)
         A_st, mask = part_mod.pack_columns(self.A_np, self.part)
-        # uploaded as packed, laid out on the device: (K, n_pad, S, 128)
-        self.A_st, self.col_sq = part_mod.tile_columns(jnp.asarray(A_st))
+        # uploaded in the layout the solvers read: (K, n_pad, S, 128)
+        self.A_st = jnp.asarray(A_st)
+        del A_st
+        self.col_sq = part_mod.column_norms(self.A_st)
         self.mask = jnp.asarray(mask)                       # (K, n_pad)
         self.b = jnp.asarray(self.b_np)
         self._solver = _get_solver(cfg.solver)

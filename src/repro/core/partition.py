@@ -8,21 +8,24 @@ Two strategies, mirroring the paper:
                     per partition.
 
 Both return a permutation + per-worker index sets. ``pack_columns``
-stacks the worker blocks on the host as (K, mp, n_pad) (columns and
-rows zero-padded), and ``tile_columns`` lays that stack out once on the
-device as the (K, n_pad, S, 128) column tiles that the virtual-worker
-and shard_map drivers and the SCD solvers read.
+lays the worker blocks out on the host as the (K, n_pad, S, 128) column
+tiles that the virtual-worker and shard_map drivers and the SCD solvers
+read: columns and rows zero-padded, S = ``tiling.column_rows(m)`` rows
+of 128 lanes, whole (8, 128) tiles. The stack is uploaded as it is: its
+minor dimensions, a column's rows of lanes, need no padding on the
+device, where a (K, m, n_pad) stack's minor n_pad would be padded to
+128 lanes (18x the stack at n_pad = 7). ``column_norms`` then reads the
+uploaded stack once for the columns' squared norms.
 """
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.tiling import LANE
+from repro.kernels.tiling import LANE, column_rows
 
 
 @dataclass(frozen=True)
@@ -66,11 +69,13 @@ def partition_imbalance(part: Partition, nnz_per_col: np.ndarray) -> float:
 
 
 def pack_columns(A: np.ndarray, part: Partition) -> tuple[np.ndarray, np.ndarray]:
-    """Stack worker column-blocks into (K, mp, n_pad) with zero padding:
-    columns to the common width n_pad, rows to mp = ceil(m/128)*128, the
-    lane multiple ``tile_columns`` lays out.
+    """Lay worker column-blocks out as (K, n_pad, S, 128) with zero
+    padding: columns to the common width n_pad, rows to
+    mp = S * 128, S = ``column_rows(m)``. Column j of worker k is the
+    contiguous, tile-aligned slab ``[k, j]``: the (S, 128) tile the SCD
+    kernel fetches and computes on.
 
-    Returns (A_stacked, mask) where mask is (K, n_pad) with 1.0 for real
+    Returns (A_tiles, mask) where mask is (K, n_pad) with 1.0 for real
     columns. Zero-padded columns have zero norm; the SCD solvers guard
     against picking them (update is exactly 0 for an all-zero column, and
     the sampling distribution masks them out). Zero rows change no dot
@@ -78,29 +83,19 @@ def pack_columns(A: np.ndarray, part: Partition) -> tuple[np.ndarray, np.ndarray
     """
     m, _ = A.shape
     K, n_pad = part.K, part.n_padded
-    out = np.zeros((K, -(-m // LANE) * LANE, n_pad), dtype=A.dtype)
+    S = column_rows(m)
+    out = np.zeros((K, n_pad, S * LANE), dtype=A.dtype)
     mask = np.zeros((K, n_pad), dtype=A.dtype)
     for k, ids in enumerate(part.owned):
-        out[k, :m, : len(ids)] = A[:, ids]
+        out[k, : len(ids), :m] = A[:, ids].T
         mask[k, : len(ids)] = 1.0
-    return out, mask
+    return out.reshape(K, n_pad, S, LANE), mask
 
 
-@functools.partial(jax.jit, donate_argnums=0)
-def tile_columns(A_st: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """The packed (K, mp, n_pad) stack on the device -> its
-    (K, n_pad, S, 128) column tiles, S = mp / 128, and their (K, n_pad)
-    squared norms.
-
-    Column j of worker k becomes the contiguous, tile-aligned slab
-    ``[k, j]``: the (S, 128) tile the SCD kernel fetches and computes on.
-    The packed stack is donated, so the tiles can take its buffer: the
-    layout costs one stack of device memory more, not two.
-    """
-    K, mp, n_pad = A_st.shape
-    assert mp % LANE == 0, mp
-    tiles = A_st.reshape(K, mp // LANE, LANE, n_pad).transpose(0, 3, 1, 2)
-    return tiles, jnp.sum(tiles * tiles, axis=(2, 3))
+@jax.jit
+def column_norms(A_tiles: jax.Array) -> jax.Array:
+    """The (K, n_pad) squared norms of the packed columns' tiles."""
+    return jnp.sum(A_tiles * A_tiles, axis=(2, 3))
 
 
 def unpack_alpha(alpha_stacked: np.ndarray, part: Partition, n: int) -> np.ndarray:

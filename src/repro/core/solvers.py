@@ -48,7 +48,7 @@ def scd_steps(A_k: jax.Array, col_sq: jax.Array, alpha_k: jax.Array,
 
     Args:
       A_k:    (n_local, S, 128) the local column block, lane-tiled by
-              ``partition.tile_columns``: column j is ``A_k[j]`` with its
+              ``partition.pack_columns``: column j is ``A_k[j]`` with its
               m rows zero-padded to S*128 (zero-padded cols ok).
       col_sq: (n_local,) squared column norms of A_k.
       alpha_k:(n_local,) local coordinates of alpha.

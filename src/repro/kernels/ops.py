@@ -4,7 +4,7 @@
 ``repro.kernels.ref.scd_steps_ref`` exactly, so the two are drop-in
 interchangeable as CoCoA local solvers (``CoCoAConfig.solver``). Both
 take the worker's block in the lane-tiled ``(n_local, S, 128)`` layout
-of ``partition.tile_columns``. The kernel fetches each visited column
+of ``partition.pack_columns``. The kernel fetches each visited column
 from that block itself (an index-driven DMA, see ``repro.kernels.scd``),
 so the wrapper only picks out the visited columns' squared norms and
 turns the kernel's residual into the update.

@@ -12,7 +12,17 @@ import jax
 import jax.numpy as jnp
 
 LANE = 128        # TPU lane width
+SUBLANE = 8       # f32 rows of an (8, 128) register tile
 ROW_BLOCK = 512   # rows of 128 lanes per grid step (a 256 KiB f32 tile)
+
+
+def column_rows(m: int) -> int:
+    """S, the rows of 128 lanes an m-long column of the SCD solvers'
+    stack is laid out in: a whole number of (8, 128) tiles, so that
+    every DMA of a column, or of a tile of its rows, moves whole tiles
+    (the row-tiled SCD kernel hung on a TPU v5e while its last tile's
+    DMAs moved 1,970 rows, and runs with 1,976)."""
+    return -(-m // (SUBLANE * LANE)) * SUBLANE
 
 
 def row_tiles(n: int) -> tuple[int, int]:

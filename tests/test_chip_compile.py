@@ -25,6 +25,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.tiling import column_rows
+
 M, K, N_LOCAL, H = 65_536, 8, 1_024, 1_024
 S = M // 128                 # rows of 128 lanes in a lane-tiled column
 L = M                        # the exchanged update is the m-vector
@@ -60,10 +62,38 @@ def one_chip(v5e):
     return SingleDeviceSharding(v5e.devices[0])
 
 
+def _compiled(fn, *shapes):
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text(), \
+        "no Pallas kernel in the program"
+    return compiled
+
+
 def _compile(fn, *shapes) -> str:
-    text = jax.jit(fn).lower(*shapes).compile().as_text()
-    assert "tpu_custom_call" in text, "no Pallas kernel in the program"
-    return text
+    return _compiled(fn, *shapes).as_text()
+
+
+def _scd_program(text: str) -> str:
+    """The Mosaic program of the first ``scd`` kernel in compiled HLO
+    text, without source locations."""
+    import base64
+
+    from jax._src.lib.mlir import ir
+
+    line = next(ln for ln in text.splitlines()
+                if "tpu_custom_call" in ln and re.search(r"%?scd[.\d]* =", ln))
+    body = base64.b64decode(re.search(r'"body":"([^"]*)"', line).group(1))
+    ctx = ir.Context()
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        return ir.Module.parse(body).operation.get_asm(
+            enable_debug_info=False)
+
+
+def _vmem(program: str) -> list[str]:
+    """The shapes of the VMEM buffers a Mosaic program declares."""
+    return re.findall(r"memref<([\dx]+)x\w+, #tpu.memory_space<vmem>>",
+                      program)
 
 
 def _shape(sharding, shape, dtype):
@@ -83,7 +113,7 @@ def test_scd_kernel_compiles(one_chip):
 
 
 def test_scd_kernel_compiles_at_full_epsilon_rows(one_chip):
-    """epsilon's published 400,000 rows (S = 3,125 lane rows a column):
+    """epsilon's published 400,000 rows (S = 3,128 lane rows a column):
     the kernel's VMEM is its column ring plus w and rho whatever H is,
     so a worker of n_local = H = 250 fits the chip's scoped VMEM."""
     from repro.kernels.scd import scd_pallas
@@ -93,7 +123,7 @@ def test_scd_kernel_compiles_at_full_epsilon_rows(one_chip):
     _compile(lambda A, q, i, a, w: scd_pallas(
         A, q, i, a, w, sigma=float(K), lam_eta=1.0, lam_l1=0.0,
         interpret=False),
-        s((n_local, m // 128, 128), jnp.float32),
+        s((n_local, column_rows(m), 128), jnp.float32),
         s((n_local,), jnp.float32), s((n_local,), jnp.int32),
         s((n_local,), jnp.float32), s((m,), jnp.float32))
 
@@ -134,26 +164,30 @@ def test_topk_select_compiles(one_chip):
              _shape(one_chip, (L,), jnp.float32))
 
 
-def _virtual_round(sharding, exchange, m, n_local, H_):
-    """Compiled HLO text of a K-worker virtual-driver CoCoA round on the
-    lane-tiled (K, n_local, m / 128, 128) stack, with the kernel
+def _virtual_round(sharding, exchange, m, n_local, H_, workers=K,
+                   text=True):
+    """Compiled HLO text (or, with ``text=False``, the compiled program)
+    of a virtual-driver CoCoA round of ``workers`` workers on the
+    lane-tiled (K, n_local, column_rows(m), 128) stack, with the kernel
     dispatch steered to the chip path."""
     from repro.core import distributed as dist
     from repro.core.cocoa import CoCoAConfig, _CoCoARound, _get_solver
     from repro.core.glm import GLMProblem
 
-    cfg = CoCoAConfig(K=K, H=H_, solver="scd_kernel", exchange=exchange,
+    Kw = workers
+    cfg = CoCoAConfig(K=Kw, H=H_, solver="scd_kernel", exchange=exchange,
                       partitioner="block")
     algo = _CoCoARound(cfg, GLMProblem(), _get_solver(cfg.solver))
     s = functools.partial(_shape, sharding)
-    data = (s((K, n_local, m // 128, 128), jnp.float32),
-            s((K, n_local), jnp.float32), s((K, n_local), jnp.float32))
-    rf = dist.build_virtual_round(algo, cfg.exchange, data, K=K,
+    data = (s((Kw, n_local, column_rows(m), 128), jnp.float32),
+            s((Kw, n_local), jnp.float32), s((Kw, n_local), jnp.float32))
+    rf = dist.build_virtual_round(algo, cfg.exchange, data, K=Kw,
                                   use_map=True)
     key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
                                sharding=sharding)
-    return _compile(rf.jitted, data, s((K, n_local), jnp.float32),
-                    s((m,), jnp.float32), key, s((), jnp.int32))
+    compiled = _compiled(rf.jitted, data, s((Kw, n_local), jnp.float32),
+                         s((m,), jnp.float32), key, s((), jnp.int32))
+    return compiled.as_text() if text else compiled
 
 
 @pytest.mark.parametrize("exchange,kernels", [
@@ -197,6 +231,22 @@ def _block_sized(text: str, elements: int) -> list[str]:
         if any(math.prod(map(int, d.split(","))) >= elements for d in dims):
             out.append(inst.group(1))
     return out
+
+
+@pytest.mark.parametrize("H_", [250, 16])
+def test_cell_round_keeps_resident_scd_kernel(one_chip, monkeypatch, H_):
+    """At the one-chip epsilon cells' shapes (m = 196,608, n_local = 250)
+    the round's kernel is the resident form: a ring of NBUF whole
+    (1,536, 128) columns and whole w and rho tiles in VMEM, no row
+    tiles."""
+    from repro.kernels.scd import NBUF
+    from repro.utils import compat
+
+    monkeypatch.setattr(compat, "on_tpu", lambda: True)
+    text = _virtual_round(one_chip, "persistent", 196_608, 250, H_)
+    vmem = _vmem(_scd_program(text))
+    assert f"{NBUF}x1536x128" in vmem, vmem
+    assert all(v.endswith("1536x128") for v in vmem), vmem
 
 
 @pytest.mark.parametrize("H_", [250, 16])
@@ -252,3 +302,67 @@ def test_four_chip_cell_round_copies_no_block_around_scd(v5e, monkeypatch):
     assert "all-reduce" in text
     big = _block_sized(text, m * n_local // 2)
     assert len(big) <= 1, big
+
+
+# HIGGS at its published 11,000,000 x 28, K = 4 workers on one chip
+HIGGS_M, HIGGS_K, HIGGS_N_LOCAL = 11_000_000, 4, 7
+HIGGS_S = column_rows(HIGGS_M)      # 85,944 rows of 128 lanes
+
+
+def test_scd_kernel_compiles_row_tiled_at_higgs_rows(one_chip):
+    """A 44 MB column (S = 85,944 lane rows) is far over the scoped
+    VMEM, so the kernel compiles its row-tiled form: only tiles of rows
+    in VMEM (none a whole column), w and rho left in HBM."""
+    from repro.kernels.scd import _tile_rows, scd_pallas
+
+    s = functools.partial(_shape, one_chip)
+    n, H_ = HIGGS_N_LOCAL, HIGGS_N_LOCAL
+    text = _compile(lambda A, q, i, a, w: scd_pallas(
+        A, q, i, a, w, sigma=float(HIGGS_K), lam_eta=1.0, lam_l1=0.0,
+        interpret=False),
+        s((n, HIGGS_S, 128), jnp.float32), s((H_,), jnp.float32),
+        s((H_,), jnp.int32), s((n,), jnp.float32),
+        s((HIGGS_M,), jnp.float32))
+    T = _tile_rows(HIGGS_S)
+    vmem = _vmem(_scd_program(text))
+    assert f"2x2x{T}x128" in vmem, vmem
+    assert not any(str(HIGGS_S) in v for v in vmem), vmem
+
+
+def test_higgs_cell_round_compiles(one_chip, monkeypatch):
+    """The whole virtual-driver round of the HIGGS cell (K = 4,
+    n_local = H = 7, m = 11,000,000) compiles for one chip, with the
+    row-tiled kernel in it, and fits the chip: the stack (1.23 GB), the
+    per-worker slice, the (K, m) updates and the m-vectors."""
+    from repro.analysis.graph import pallas_kernels
+    from repro.utils import compat
+
+    monkeypatch.setattr(compat, "on_tpu", lambda: True)
+    compiled = _virtual_round(one_chip, "persistent", HIGGS_M,
+                              HIGGS_N_LOCAL, HIGGS_N_LOCAL,
+                              workers=HIGGS_K, text=False)
+    text = compiled.as_text()
+    assert "scd" in pallas_kernels(text)
+    assert not any(str(HIGGS_S) in v for v in _vmem(_scd_program(text)))
+    mem = compiled.memory_analysis()
+    stack, vector = HIGGS_K * HIGGS_N_LOCAL * HIGGS_S * 512, HIGGS_S * 512
+    assert mem.argument_size_in_bytes < stack + 2 * vector   # and w
+    assert mem.temp_size_in_bytes < stack
+
+
+def test_higgs_stack_uploads_without_lane_padding(one_chip):
+    """The packed (K, n_pad, S, 128) stack of the HIGGS cell takes its
+    own size on the chip (its minor dimensions are a column's rows of
+    lanes, padded to 8 rows at most), and the device program that
+    follows the upload, the columns' norms, holds less than the stack
+    and one (K, m) stack of m-vectors besides."""
+    from repro.core.partition import column_norms
+
+    s = functools.partial(_shape, one_chip)
+    shape = (HIGGS_K, HIGGS_N_LOCAL, HIGGS_S, 128)
+    stack = math.prod(shape) * 4
+    mem = column_norms.lower(s(shape, jnp.float32)).compile() \
+        .memory_analysis()
+    assert mem.argument_size_in_bytes <= stack * (HIGGS_S + 7) // HIGGS_S
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes) < stack + HIGGS_K * HIGGS_S * 512

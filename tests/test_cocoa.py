@@ -147,7 +147,7 @@ def test_pack_unpack_roundtrip():
     A, b, _ = make_glm_data(m=64, n=100, seed=0)
     part = pt.balanced_partition((np.abs(A) > 0).sum(0), 4)
     packed, mask = pt.pack_columns(A, part)
-    assert packed.shape[0] == 4 and mask.shape == packed.shape[::2]
+    assert packed.shape[0] == 4 and mask.shape == packed.shape[:2]
     # scatter alpha back
     alpha_st = np.arange(4 * part.n_padded, dtype=np.float32).reshape(4, -1)
     alpha_st *= mask

@@ -8,15 +8,16 @@ import pytest
 pytest.importorskip("hypothesis")  # dev extra; CI installs it via .[dev]
 from hypothesis import given, settings, strategies as st
 
-from repro.core.partition import block_partition, pack_columns, tile_columns
+from repro.core.partition import block_partition, column_norms, pack_columns
 from repro.kernels import scd_steps_kernel, scd_steps_ref
+from repro.kernels.tiling import column_rows
 
 
 def tiles(A):
-    """(m, n) -> the (n, ceil(m/128), 128) block the solvers read."""
+    """(m, n) -> the (n, column_rows(m), 128) block the solvers read."""
     A = np.asarray(A, np.float32)
     packed, _ = pack_columns(A, block_partition(A.shape[1], 1))
-    return tile_columns(jnp.asarray(packed))[0][0]
+    return jnp.asarray(packed[0])
 
 
 def _mk(m, n, H, dtype, seed=0):
@@ -196,13 +197,130 @@ def test_tile_columns_layout():
     rng = np.random.default_rng(0)
     A = rng.standard_normal((200, 15)).astype(np.float32)
     part = block_partition(15, 3)
-    packed, _ = pack_columns(A, part)
-    assert packed.shape == (3, 256, 5)
-    T, col_sq = tile_columns(jnp.asarray(packed))
-    assert T.shape == (3, 5, 2, 128) and col_sq.shape == (3, 5)
-    flat = np.asarray(T).reshape(3, 5, 256)
+    T, _ = pack_columns(A, part)
+    assert T.shape == (3, 5, 8, 128)          # one whole (8, 128) tile
+    col_sq = column_norms(jnp.asarray(T))
+    assert col_sq.shape == (3, 5)
+    flat = T.reshape(3, 5, 1024)
     for k, ids in enumerate(part.owned):
         np.testing.assert_array_equal(flat[k, :, :200], A[:, ids].T)
         np.testing.assert_allclose(col_sq[k], (A[:, ids] ** 2).sum(0),
                                    rtol=1e-6)
     np.testing.assert_array_equal(flat[..., 200:], 0.0)
+
+
+# the row-tiled form, reached as the trainer reaches it: scd_pallas
+# picks it from S against the kernel's VMEM budget, shrunk here so that
+# small columns take several row tiles
+_TILE = 16                                  # rows of a tile at this budget
+
+
+@pytest.fixture
+def small_vmem(monkeypatch):
+    """A VMEM budget of 12 tiles of 16 rows: columns of S >= 40 lane rows
+    (m > 4,096) take the row-tiled form."""
+    import jax
+    from repro.kernels import scd as scd_mod
+
+    monkeypatch.setattr(scd_mod, "VMEM_BUDGET",
+                        scd_mod._TILED_BUFFERS * _TILE * 4 * 128)
+    jax.clear_caches()          # no program of another budget is reused
+    yield scd_mod
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("S,n,H,case", [
+    (40, 9, 13, "shorter last tile, rows past m"),
+    (48, 9, 13, "rows a whole number of tiles"),
+    (56, 6, 24, "repeated indices"),
+    (40, 9, 11, "padded zero columns"),
+    (40, 5, 1, "H shorter than the fetch ring"),
+    (48, 5, 2, "H shorter than the fetch ring"),
+])
+def test_row_tiled_kernel_matches_oracle(small_vmem, S, n, H, case):
+    """The row-tiled form (H + 1 sweeps over tiles of rows, rho in HBM)
+    agrees with the jnp oracle and a float64 SCD on the plain matrix:
+    across a last tile shorter than the rest, zero rows past m, a
+    revisited column, a zero column, and sweeps of one or two steps."""
+    m = S * 128 - (300 if case.startswith("shorter") else 0)
+    assert column_rows(m) == S and small_vmem._tile_rows(S) == _TILE
+    rng = np.random.default_rng(S + n + H)
+    A = rng.standard_normal((m, n)).astype(np.float32)
+    idx = rng.integers(0, n, H).astype(np.int32)
+    if case == "repeated indices":
+        idx[::2] = idx[1]
+    if case == "padded zero columns":
+        A[:, -3:] = 0.0
+        idx[::3] = n - 1
+    colsq = jnp.asarray((A.astype(np.float64) ** 2).sum(0), jnp.float32)
+    alpha = jnp.asarray(rng.standard_normal(n) * 0.1, jnp.float32)
+    w = jnp.asarray(rng.standard_normal(m), jnp.float32)
+    kw = dict(sigma=4.0, lam=1.5, eta=0.6)
+    dv_r, a_r = scd_steps_ref(tiles(A), colsq, alpha, w, jnp.asarray(idx),
+                              **kw)
+    dv_k, a_k = scd_steps_kernel(tiles(A), colsq, alpha, w,
+                                 jnp.asarray(idx), **kw)
+    dv_n, a_n = scd_numpy(A, colsq, alpha, w, idx, **kw)
+    assert dv_k.shape == (m,) and a_k.shape == (n,)
+    np.testing.assert_allclose(dv_k, dv_r, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(a_k, a_r, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(dv_k, dv_n, rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(a_k, a_n, rtol=1e-4, atol=1e-4)
+    if case == "padded zero columns":
+        np.testing.assert_array_equal(a_k[-3:], alpha[-3:])
+
+
+def test_resident_form_below_the_budget():
+    """The kernel keeps rho and a column ring in VMEM while they fit:
+    at the benchmark's 196,608 rows and epsilon's 400,000; HIGGS's
+    11,000,000 rows take the row-tiled form, in tiles that fit."""
+    from repro.kernels import scd as scd_mod
+
+    assert scd_mod._tile_rows(column_rows(196_608)) is None
+    assert scd_mod._tile_rows(column_rows(400_000)) is None
+    T = scd_mod._tile_rows(column_rows(11_000_000))
+    assert T and T % 8 == 0
+    assert scd_mod._TILED_BUFFERS * T * 4 * 128 <= scd_mod.VMEM_BUDGET
+
+
+def test_trainer_tall_problem_reaches_ridge_optimum(small_vmem, monkeypatch):
+    """CoCoA through ``CoCoATrainer.run`` on a tall, skinny problem
+    (40,000 x 28, K = 4, H = n_local = 7), the local solve in its
+    row-tiled form: it certifies eps against the float64 normal
+    equations' optimum, the primal it reports is the float64 primal at
+    its alpha, and it follows the jnp solver's trajectory."""
+    from repro.core import CoCoAConfig, CoCoATrainer
+
+    monkeypatch.setattr(small_vmem, "VMEM_BUDGET",
+                        small_vmem._TILED_BUFFERS * 128 * 4 * 128)
+    m, n, K, eps = 40_000, 28, 4, 1e-3
+    assert small_vmem._tile_rows(column_rows(m)) == 128
+    rng = np.random.default_rng(0)
+    F = rng.standard_normal((m, 4)) @ rng.standard_normal((4, n))
+    A = (0.8 * rng.standard_normal((m, n)) + 0.5 * F).astype(np.float32)
+    A /= np.linalg.norm(A, axis=1, keepdims=True)
+    b = np.sign(A @ rng.standard_normal(n)
+                + rng.standard_normal(m)).astype(np.float32)
+    A64, b64 = A.astype(np.float64), b.astype(np.float64)
+    alpha_star = np.linalg.solve(A64.T @ A64 + np.eye(n), A64.T @ b64)
+
+    def primal(a):
+        return 0.5 * np.sum((A64 @ a - b64) ** 2) + 0.5 * a @ a
+
+    p_star, p_zero = primal(alpha_star), primal(np.zeros(n))
+    runs = {}
+    for solver in ("scd_kernel", "scd_ref"):
+        tr = CoCoATrainer(CoCoAConfig(K=K, H=n // K, lam=1.0, solver=solver,
+                                      partitioner="block", seed=3), A, b)
+        hist = tr.run(500, target_eps=eps, p_star=p_star)
+        runs[solver] = hist, tr.alpha_final
+    hist, alpha = runs["scd_kernel"]
+    p64 = primal(alpha.astype(np.float64))
+    # P(alpha) - p* = 1/2 ||alpha - alpha*||^2 in the metric A^T A + I,
+    # so this bounds alpha's distance from the optimum as well
+    assert hist.rounds[-1] < 500
+    assert (p64 - p_star) / (p_zero - p_star) <= eps * 1.01
+    assert abs(hist.primal[-1] - p64) / (p_zero - p_star) <= 1e-6
+    hist_ref, alpha_ref = runs["scd_ref"]
+    assert hist.rounds == hist_ref.rounds
+    np.testing.assert_allclose(alpha, alpha_ref, rtol=1e-4, atol=1e-6)
