@@ -139,12 +139,22 @@ class _CoCoARound:
         self.cfg, self.problem, self.solver = cfg, problem, solver
 
     def local_step(self, data_k, alpha_k, w, key, t):
+        return self._solve(*data_k, alpha_k, w, key)
+
+    def local_step_at(self, data, k, alpha_k, w, key, t):
+        """Worker ``k``'s round on the whole stack: only its column
+        norms and mask are picked out, and the solver reads block k of
+        ``A_st`` where it lies (``scd_steps_kernel``'s ``worker``)."""
+        A_st, col_sq, mask = data
+        return self._solve(A_st, col_sq[k], mask[k], alpha_k, w, key,
+                           worker=k)
+
+    def _solve(self, A, col_sq_k, mask_k, alpha_k, w, key, **worker):
         cfg = self.cfg
-        A_k, col_sq_k, mask_k = data_k
         logits = jnp.where(mask_k > 0, 0.0, -jnp.inf)
         idx = jax.random.categorical(key, logits,
                                      shape=(cfg.H,)).astype(jnp.int32)
-        dv, alpha_new = self.solver(A_k, col_sq_k, alpha_k, w, idx,
+        dv, alpha_new = self.solver(A, col_sq_k, alpha_k, w, idx, **worker,
                                     sigma=cfg.sigma_val, lam=cfg.lam,
                                     eta=cfg.eta)
         if cfg.solver == "scd_fixed":

@@ -915,6 +915,12 @@ class RoundAlgorithm(Protocol):
         ``update`` is the 1-D vector to be all-reduced."""
         ...
 
+    # Optional: ``local_step_at(data, k, local_k, shared, key, t)``, the
+    # same round of worker ``k`` given the whole ``(K, ...)`` stacked
+    # data and the (traced) index. The virtual driver's ``lax.map``
+    # calls it where the algorithm has it, so that a solver which reads
+    # the stack in place needs no per-worker slice of the data.
+
     def apply_update(self, shared, total_update, t):
         """New shared state from the all-reduced update (round ``t``)."""
         ...
@@ -988,7 +994,11 @@ def build_virtual_round(algo: RoundAlgorithm, exchange=None, data=None,
     constant, so the program text does not grow with the data;
     ``round_fn.lower(local, shared, key, t)`` lowers it ahead of time.
     ``use_map`` runs workers with ``lax.map``
-    instead of ``vmap`` (needed for interpret-mode Pallas solvers).
+    instead of ``vmap`` (needed for interpret-mode Pallas solvers); it
+    maps over the worker index, the local state and the keys, and hands
+    each step the whole data with its index through the algorithm's
+    ``local_step_at`` where it has one (no worker's block is sliced out
+    of the stack), else ``local_step`` on the worker's slice.
     Under a stale mode the ``shared`` slot is the ``(shared, queue)``
     pair from :func:`init_exchange_state`: workers compute against
     state absorbed through round ``t-1-k``, the oldest pending
@@ -1013,6 +1023,12 @@ def build_virtual_round(algo: RoundAlgorithm, exchange=None, data=None,
     stateful = bool(getattr(comm.codec, "stateful", False))
     reweight = (not membership.empty
                 and getattr(algo, "live_reweight", False))
+    step_at = getattr(algo, "local_step_at", None)
+    if step_at is None:
+        def step_at(data, k, local_k, shared, key, t):
+            return algo.local_step(
+                jax.tree_util.tree_map(lambda x: x[k], data), local_k,
+                shared, key, t)
 
     @jax.jit
     def jitted(data, local, shared, key, t):
@@ -1024,9 +1040,9 @@ def build_virtual_round(algo: RoundAlgorithm, exchange=None, data=None,
         with jax.named_scope("workers"):
             if use_map:
                 upd, local_new = lax.map(
-                    lambda args: algo.local_step(args[0], args[1], shared,
-                                                 args[2], t),
-                    (data, local, keys))
+                    lambda args: step_at(data, args[0], args[1], shared,
+                                         args[2], t),
+                    (jnp.arange(K), local, keys))
             else:
                 upd, local_new = jax.vmap(
                     lambda d, l, k_: algo.local_step(d, l, shared, k_, t))(

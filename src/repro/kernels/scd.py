@@ -3,7 +3,7 @@
 This is the TPU-native analogue of the paper's "offload the hot loop to
 an optimized C++ module": the H sequential coordinate-descent steps run
 in one kernel invocation, and the kernel fetches each visited column
-from the worker's block in HBM itself.
+from the workers' stack in HBM itself, at its worker's row.
 
 TPU adaptation (vs the CPU/C++ original):
   * The worker's block is stored column-major and LANE-TILED:
@@ -18,7 +18,11 @@ TPU adaptation (vs the CPU/C++ original):
   * Index-driven fetch. The coordinate stream is prefetched into SMEM,
     so the kernel knows every column it will visit and reads only
     those, straight from the block: no (H, m) column matrix is built
-    outside the kernel.
+    outside the kernel. The block is not cut out of the
+    ``(K, n_local, S, 128)`` stack either: the kernel takes the whole
+    stack, left in HBM, with the worker's index k as one more SMEM
+    word, and fetches column j of worker k as ``A[k, j]``. A lone
+    ``(n_local, S, 128)`` block is a stack of one, worker 0.
   * The per-step scalars — the coordinate index, sigma*||c_j||^2,
     1/denom and the soft-threshold level lam_l1/denom — are precomputed
     VECTORIZED outside the kernel and prefetched whole into SMEM, so
@@ -26,7 +30,7 @@ TPU adaptation (vs the CPU/C++ original):
     reduction. The alpha block lives in SMEM too: the dynamically
     indexed ``alpha[j]`` read and store are scalar-unit work, and the
     TPU cannot store a scalar to VMEM. SMEM therefore holds
-    4 * H + 2 * n_local words.
+    4 * H + 2 * n_local + 1 words, the last the worker's index.
 
 Two forms, picked from S alone (``_tile_rows``; nobody sets it):
 
@@ -116,8 +120,9 @@ def _copy_alpha(alpha_in_ref, alpha_ref):
     lax.fori_loop(0, alpha_ref.shape[0], copy, 0)
 
 
-def _scd_kernel(sigma: float, idx_ref, scsq_ref, dinv_ref, thr_ref, A_hbm,
-                alpha_in_ref, w_ref, alpha_ref, rho_ref, cols, sems):
+def _scd_kernel(sigma: float, idx_ref, scsq_ref, dinv_ref, thr_ref,
+                worker_ref, A_hbm, alpha_in_ref, w_ref, alpha_ref, rho_ref,
+                cols, sems):
     """The resident form: all H = idx.shape[0] sequential SCD updates
     with rho and the column ring in VMEM.
 
@@ -126,11 +131,12 @@ def _scd_kernel(sigma: float, idx_ref, scsq_ref, dinv_ref, thr_ref, A_hbm,
     scalar unit); the fetched columns and the rho accumulator are
     (S, 128) VMEM tiles."""
     H = idx_ref.shape[0]
+    k = worker_ref[0]
 
     def fetch(step):
         slot = step % NBUF
-        return pltpu.make_async_copy(A_hbm.at[idx_ref[step]], cols.at[slot],
-                                     sems.at[slot])
+        return pltpu.make_async_copy(A_hbm.at[k, idx_ref[step]],
+                                     cols.at[slot], sems.at[slot])
 
     for step in range(min(NBUF - 1, H)):
         fetch(step).start()
@@ -163,13 +169,15 @@ def _scd_kernel(sigma: float, idx_ref, scsq_ref, dinv_ref, thr_ref, A_hbm,
 
 
 def _scd_tiled_kernel(sigma: float, T: int, idx_ref, scsq_ref, dinv_ref,
-                      thr_ref, A_hbm, alpha_in_ref, w_hbm, alpha_ref,
-                      rho_hbm, src, cols, out, sem_in, sem_out, tail_src,
-                      tail_cols, tail_out, tail_sem_in, tail_sem_out):
+                      thr_ref, worker_ref, A_hbm, alpha_in_ref, w_hbm,
+                      alpha_ref, rho_hbm, src, cols, out, sem_in, sem_out,
+                      tail_src, tail_cols, tail_out, tail_sem_in,
+                      tail_sem_out):
     """The row-tiled form: H + 1 sweeps over the rows in tiles of T,
     with rho in HBM (module docstring). ``src``/``cols``/``out`` are the
     two slots of full tiles, ``tail_*`` the ragged last tile."""
     H = idx_ref.shape[0]
+    k = worker_ref[0]
     S = w_hbm.shape[0]
     n_full, tail = divmod(S, T)
     scalars = (idx_ref, scsq_ref, dinv_ref, thr_ref, alpha_ref)
@@ -182,10 +190,10 @@ def _scd_tiled_kernel(sigma: float, T: int, idx_ref, scsq_ref, dinv_ref,
         # (HBM rows, full-tile buffer of a slot, tail buffer, semaphore)
         ops = [(source, lambda s: src.at[s], tail_src, 0)]
         if prev is not None:
-            ops.append((A_hbm.at[prev], lambda s: cols.at[s, 0],
+            ops.append((A_hbm.at[k, prev], lambda s: cols.at[s, 0],
                         tail_cols.at[0], 1))
         if cur is not None:
-            ops.append((A_hbm.at[cur], lambda s: cols.at[s, 1],
+            ops.append((A_hbm.at[k, cur], lambda s: cols.at[s, 1],
                         tail_cols.at[1], 2))
 
         def reads(t, slot):
@@ -276,24 +284,31 @@ def _scd_tiled_kernel(sigma: float, T: int, idx_ref, scsq_ref, dinv_ref,
 @functools.partial(jax.jit, static_argnames=("sigma", "lam_eta", "lam_l1",
                                              "interpret"))
 def scd_pallas(A: jax.Array, csq: jax.Array, idx: jax.Array,
-               alpha: jax.Array, w: jax.Array, *, sigma: float,
-               lam_eta: float, lam_l1: float, interpret: bool | None = None
+               alpha: jax.Array, w: jax.Array, worker: jax.Array | int = 0,
+               *, sigma: float, lam_eta: float, lam_l1: float,
+               interpret: bool | None = None
                ) -> tuple[jax.Array, jax.Array]:
-    """Run H = idx.shape[0] SCD steps (any H >= 1), fetching column
-    ``A[idx[s]]`` at step s, in the form S picks (module docstring).
+    """Run H = idx.shape[0] SCD steps (any H >= 1) of worker
+    ``worker``, fetching column ``A[worker, idx[s]]`` at step s, in the
+    form S picks (module docstring).
 
     Args:
-      A:     (n_local, S, 128) the worker's lane-tiled column block,
-             S = ``column_rows(m)``, rows past m zero.
+      A:     (K', n_local, S, 128) the workers' lane-tiled column
+             blocks, S = ``column_rows(m)``, rows past m zero; or one
+             worker's (n_local, S, 128) block, a stack of one.
       csq:   (H,) squared norms of the visited columns.
       idx:   (H,) int32 local coordinate index per step.
       alpha: (n_local,) f32 local coordinates.
       w:     (m,) round-start shared residual, f32.
+      worker: the int32 index k of the worker's block in ``A``, traced
+             or not; 0 for a lone block.
     Returns:
       (alpha_new (n_local,) f32, rho (m,) f32).
     """
     interpret = compat.default_interpret(interpret)
-    n_local, S, lane = A.shape
+    if A.ndim == 3:
+        A = A[None]                     # a stack of one: a bitcast
+    _, n_local, S, lane = A.shape
     (H,), m = idx.shape, w.shape[0]
     assert H >= 1, H
     assert lane == _LANE and S == column_rows(m), (A.shape, m)
@@ -334,11 +349,12 @@ def scd_pallas(A: jax.Array, csq: jax.Array, idx: jax.Array,
     alpha_out, rho = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            # idx, sigma*csq, 1/denom, threshold: whole (H,) in SMEM
-            num_scalar_prefetch=4,
+            # idx, sigma*csq, 1/denom, threshold: whole (H,) in SMEM;
+            # the worker's index, (1,)
+            num_scalar_prefetch=5,
             grid=(1,),
             in_specs=[
-                pl.BlockSpec(memory_space=pl.ANY),        # A, left in HBM
+                pl.BlockSpec(memory_space=pl.ANY),        # stack, in HBM
                 smem,                                     # alpha in
                 vector_spec,                              # w
             ],
@@ -350,6 +366,7 @@ def scd_pallas(A: jax.Array, csq: jax.Array, idx: jax.Array,
         ],
         interpret=interpret,
         name="scd",
-    )(idx.astype(jnp.int32), scsq, dinv, thr, A,
+    )(idx.astype(jnp.int32), scsq, dinv, thr,
+      jnp.asarray(worker, jnp.int32).reshape(1), A,
       alpha.astype(jnp.float32), w3)
     return alpha_out, rho.reshape(mp)[:m]

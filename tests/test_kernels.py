@@ -324,3 +324,97 @@ def test_trainer_tall_problem_reaches_ridge_optimum(small_vmem, monkeypatch):
     hist_ref, alpha_ref = runs["scd_ref"]
     assert hist.rounds == hist_ref.rounds
     np.testing.assert_allclose(alpha, alpha_ref, rtol=1e-4, atol=1e-6)
+
+
+def _stack(K, m, n, seed):
+    """A random (K, n, column_rows(m), 128) stack, its (K, n) norms and
+    (K, n) alphas, an m-vector w and K streams of coordinates."""
+    rng = np.random.default_rng(seed)
+    A = rng.standard_normal((m, K * n)).astype(np.float32)
+    packed, _ = pack_columns(A, block_partition(K * n, K))
+    A_st = jnp.asarray(packed)
+    alpha = jnp.asarray(rng.standard_normal((K, n)) * 0.1, jnp.float32)
+    w = jnp.asarray(rng.standard_normal(m), jnp.float32)
+    return A_st, column_norms(A_st), alpha, w, rng
+
+
+def _assert_stack_reads_as_block(A_st, col_sq, alpha, w, rng, H):
+    """For every worker k: the kernel given the whole stack and k gives
+    what it gives on block k alone, bit for bit, and the oracle's
+    result on block k."""
+    kw = dict(sigma=4.0, lam=1.5, eta=0.6)
+    for k in range(A_st.shape[0]):
+        idx = jnp.asarray(rng.integers(0, A_st.shape[1], H), jnp.int32)
+        dv_s, a_s = scd_steps_kernel(A_st, col_sq[k], alpha[k], w, idx,
+                                     jnp.int32(k), **kw)
+        dv_b, a_b = scd_steps_kernel(A_st[k], col_sq[k], alpha[k], w, idx,
+                                     **kw)
+        np.testing.assert_array_equal(dv_s, dv_b)
+        np.testing.assert_array_equal(a_s, a_b)
+        dv_r, a_r = scd_steps_ref(A_st[k], col_sq[k], alpha[k], w, idx,
+                                  **kw)
+        np.testing.assert_allclose(dv_s, dv_r, rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(a_s, a_r, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("K,m,n,H", [
+    (3, 200, 12, 40), (4, 384, 10, 2), (2, 130, 6, 30), (5, 1024, 8, 1)])
+def test_kernel_reads_stack_at_worker(K, m, n, H):
+    """The resident form, given the whole (K, n, S, 128) stack and a
+    traced worker index k, fetches block k's columns from the stack:
+    the same steps as on the sliced block, for every k, including H
+    shorter than the fetch ring and H = 1."""
+    A_st, col_sq, alpha, w, rng = _stack(K, m, n, seed=K + m + n + H)
+    _assert_stack_reads_as_block(A_st, col_sq, alpha, w, rng, H)
+
+
+@pytest.mark.parametrize("K,S,n,H", [
+    (3, 40, 9, 13), (2, 48, 5, 2), (4, 40, 5, 1)])
+def test_row_tiled_kernel_reads_stack_at_worker(small_vmem, K, S, n, H):
+    """The row-tiled form reads block k's columns from the stack in its
+    sweeps, with a last tile shorter than the rest (S = 40) or none
+    (S = 48): the same steps as on the sliced block, for every k."""
+    m = S * 128 - 300
+    assert column_rows(m) == S and small_vmem._tile_rows(S) == _TILE
+    A_st, col_sq, alpha, w, rng = _stack(K, m, n, seed=K + S + n + H)
+    _assert_stack_reads_as_block(A_st, col_sq, alpha, w, rng, H)
+
+
+@pytest.mark.parametrize("form,exchange", [
+    ("resident", "persistent"),
+    ("resident", "compressed:ef:int4/stale:k=1"),
+    ("row-tiled", "persistent")])
+def test_trainer_stack_path_matches_slice_path(request, monkeypatch, form,
+                                               exchange):
+    """``CoCoATrainer.run`` on the kernel path, whose ``lax.map`` hands
+    each worker's kernel the whole stack and its index, records the
+    history and returns the alpha of the same run through a per-worker
+    slice of the stack (the driver's path for an algorithm without
+    ``local_step_at``), bit for bit, and follows ``scd_ref``'s
+    trajectory."""
+    from repro.core import CoCoAConfig, CoCoATrainer
+    from repro.core import cocoa
+    from repro.data import make_glm_data
+
+    m, n, K = (4_800, 24, 3) if form == "row-tiled" else (200, 96, 4)
+    if form == "row-tiled":
+        assert request.getfixturevalue("small_vmem")._tile_rows(
+            column_rows(m)) == _TILE
+    A, b, _ = make_glm_data(m=m, n=n, density=0.5, seed=7)
+
+    def run(solver):
+        cfg = CoCoAConfig(K=K, H=n // K, solver=solver, exchange=exchange,
+                          partitioner="block", seed=5)
+        tr = CoCoATrainer(cfg, A, b)
+        return tr.run(rounds=8), tr.alpha_final
+
+    hist, alpha = run("scd_kernel")
+    with monkeypatch.context() as mp:
+        mp.delattr(cocoa._CoCoARound, "local_step_at")
+        hist_sl, alpha_sl = run("scd_kernel")
+    assert hist.rounds == hist_sl.rounds == list(range(1, 9))
+    assert hist.primal == hist_sl.primal
+    np.testing.assert_array_equal(alpha, alpha_sl)
+    hist_ref, alpha_ref = run("scd_ref")
+    np.testing.assert_allclose(hist.primal, hist_ref.primal, rtol=1e-4)
+    np.testing.assert_allclose(alpha, alpha_ref, rtol=1e-4, atol=1e-6)
